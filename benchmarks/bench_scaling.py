@@ -1,50 +1,45 @@
-"""Paper Fig. 9 analogue: strong scaling of sharded mining over host
-devices (subprocess per device count; on this 1-core box the numbers show
-correct *work partitioning*, not wall-clock speedup — on real multi-core
-or TPU hosts the same harness measures true scaling)."""
+"""Paper Fig. 9 analogue: strong scaling of sharded mining.
+
+One process sweeps meshes over the first 1, 2, 4, ... of its own
+devices (``jax.devices()[:n]``) — a chip belongs to one process, so no
+child processes are started.  On a CPU host the devices are virtual
+(``XLA_FLAGS=--xla_force_host_platform_device_count=N``) and the numbers
+show correct work partitioning, not speedup.  A count that differs from
+the one-device run raises, so a failing sweep exits non-zero.
+"""
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
+import time
+
+import jax
+import numpy as np
+from jax.sharding import Mesh
 
 from benchmarks.common import emit
-
-_CODE = """
-import time, jax, numpy as np
-from repro.graph import generators as G
 from repro.core import make_tc_app, mine_sharded
-g = G.erdos_renyi(200, 0.05, seed=3)
-n = jax.device_count()
-mesh = jax.make_mesh((n,), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
-caps = ((8192, 4096),)
-mine_sharded(g, make_tc_app(), mesh, caps)   # compile
-t0 = time.perf_counter()
-cnt, _, ovf = mine_sharded(g, make_tc_app(), mesh, caps)
-print(f"RESULT {time.perf_counter()-t0:.4f} {cnt} {ovf}")
-"""
+from repro.graph import generators as G
 
 
 def run(small: bool = True) -> list[str]:
-    out = []
-    counts = [1, 2, 4] if small else [1, 2, 4, 8]
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    g = G.erdos_renyi(200, 0.05, seed=3)
+    caps = ((8192, 4096),)
+    n_avail = len(jax.devices())
+    counts = [n for n in ((1, 2, 4) if small else (1, 2, 4, 8))
+              if n <= n_avail]
+    out, ref = [], None
     for n in counts:
-        env = dict(os.environ,
-                   XLA_FLAGS=f"--xla_force_host_platform_device_count={n}",
-                   PYTHONPATH=src)
-        r = subprocess.run([sys.executable, "-c", _CODE],
-                           capture_output=True, text=True, env=env,
-                           timeout=900)
-        if r.returncode != 0:
-            out.append(emit(f"fig9/tc-scaling/{n}dev", float("nan"),
-                            "FAIL"))
-            continue
-        line = [l for l in r.stdout.splitlines()
-                if l.startswith("RESULT")][0].split()
-        out.append(emit(f"fig9/tc-scaling/{n}dev", float(line[1]),
-                        f"count={line[2]}"))
+        mesh = Mesh(np.asarray(jax.devices()[:n]), ("data",))
+        mine_sharded(g, make_tc_app(), mesh, caps)          # compile
+        t0 = time.perf_counter()
+        cnt, _, ovf = mine_sharded(g, make_tc_app(), mesh, caps)
+        dt = time.perf_counter() - t0
+        if ovf:
+            raise RuntimeError(f"fig9/tc-scaling/{n}dev: capacity overflow")
+        ref = cnt if ref is None else ref
+        if cnt != ref:
+            raise RuntimeError(f"fig9/tc-scaling/{n}dev: count {cnt} != "
+                               f"one-device count {ref}")
+        out.append(emit(f"fig9/tc-scaling/{n}dev", dt, f"count={cnt}"))
     return out
 
 

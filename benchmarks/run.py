@@ -16,6 +16,8 @@ def main() -> None:
     ap.add_argument("--sections", default="apps,handopt,ablations,memory,"
                                           "scaling,backends,roofline")
     args = ap.parse_args()
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     small = not args.full
     sections = args.sections.split(",")
     print("name,us_per_call,derived")

@@ -2,10 +2,11 @@
 from repro.core.api import GraphCtx, MiningApp, make_ctx
 from repro.core.engine import (Miner, MineResult, bounded_mine_edge,
                                bounded_mine_vertex, mine_sharded,
-                               run_level_loop)
+                               run_level_loop, sharded_inputs,
+                               sharded_program, sharded_results)
 from repro.core.plan import (HostCapPolicy, MiningExecutor, MiningPlan,
                              PlanCache, PlanCapPolicy, estimate_plan,
-                             plan_signature, profile_distance,
+                             plan_program, plan_signature, profile_distance,
                              transfer_caps)
 from repro.core.phases import (PhaseBackend, available_backends, get_backend,
                                register_backend)

@@ -15,9 +15,11 @@ search parameters; it is what the helper routines of Listing 2
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Callable, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -26,9 +28,21 @@ from repro.graph.csr import (CSRGraph, PackedGraph, pack_adjacency,
 from repro.sparse.intersect import adj_contains
 
 
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["row_ptr", "col_idx", "labels", "edge_uid", "usrc", "udst",
+                 "packed"],
+    meta_fields=["n_vertices", "n_edges", "max_degree", "n_steps", "search",
+                 "n_labels", "n_uedges"])
 @dataclasses.dataclass(frozen=True)
 class GraphCtx:
-    """Device-side graph context threaded through all hooks."""
+    """Device-side graph context threaded through all hooks.
+
+    A pytree: the arrays (and the ``packed`` bitmap) are leaves, the ints
+    and ``search`` are static aux data.  Compiled programs take it as an
+    argument, so the graph is an input buffer of the executable and never
+    a constant folded into its HLO.
+    """
 
     row_ptr: jnp.ndarray          # i32[n+1]
     col_idx: jnp.ndarray          # i32[m]

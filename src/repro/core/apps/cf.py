@@ -71,7 +71,10 @@ def make_cf_app(k: int, use_dag: bool = True,
         # ever materialized (the paper's eager pruning, Listing 3)
         kk = len(emb_cols)
         ok = u >= 0
-        for j in range(kk):
+        # with eager pruning every candidate is a neighbour of the last
+        # vertex, which it was drawn from: that probe is known true, and
+        # leaving it out lets the compiler drop it
+        for j in range(kk - 1 if eager_prune else kk):
             ok = ok & conn[j]
         if use_dag:
             for j in range(kk):

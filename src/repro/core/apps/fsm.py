@@ -25,7 +25,6 @@ Listing 5 is.
 from __future__ import annotations
 
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core.api import GraphCtx, MiningApp
 
@@ -35,17 +34,13 @@ def make_fsm_app(k: int, min_support: int,
     def to_add_vertex_mask(ctx: GraphCtx) -> jnp.ndarray:
         if ctx.labels is None or min_support <= 0:
             return jnp.ones((ctx.n_vertices,), bool)
-        # host-side histogram over the concrete label array: runs once at
-        # trace time and bakes into the executable as a constant — only
-        # the per-candidate mask gather (done by the backend: in XLA on
-        # the reference path, inside the fused edge kernel on the Pallas
-        # paths) is on the compiled hot path
-        freq_np = np.bincount(
-            np.clip(np.asarray(ctx.labels), 0, ctx.n_labels),
-            minlength=ctx.n_labels + 1).astype(np.int32)
-        label_freq = jnp.asarray(freq_np)
-        freq = label_freq[jnp.clip(ctx.labels, 0, ctx.n_labels)]
-        return freq >= min_support
+        # the label histogram is computed in the program from the labels
+        # it is given (a traced argument of compiled runs); the backend
+        # then gathers the mask per candidate — in XLA on the reference
+        # path, inside the fused edge kernel on the Pallas paths
+        lab = jnp.clip(ctx.labels, 0, ctx.n_labels)
+        label_freq = jnp.bincount(lab, length=ctx.n_labels + 1)
+        return label_freq[lab] >= min_support
 
     return MiningApp(name=f"{k}-fsm", kind="edge", max_size=k,
                      needs_reduce=True, needs_filter=True,

@@ -43,6 +43,7 @@ as ``Miner.run(resume_from=...)`` (see repro.train.checkpoint).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import time
 from typing import Callable, Optional
@@ -128,10 +129,13 @@ class _PhaseOps:
     arguments — the host driver's mode, where per-level closures are
     compiled once per bucketed capacity and reused across runs and blocks.
     ``jit=False`` leaves the ops raw so a whole mining run composes into a
-    single jit (executor / ``shard_map`` / dry-run).  In jitted (host)
-    mode every op is additionally bracketed by a trace span
-    (:func:`_obs_op`) — the ``_PhaseOps`` seam is where backend op
-    timings come from, uniformly for all registered backends.
+    single jit (executor / ``shard_map`` / dry-run), whose caller builds
+    the ops from the ctx it received as an argument.  Either way every op
+    takes the graph as its first (bound) argument: a jit never closes
+    over a graph array.  In jitted (host) mode every op is additionally
+    bracketed by a trace span (:func:`_obs_op`) — the ``_PhaseOps`` seam
+    is where backend op timings come from, uniformly for all registered
+    backends.
     """
 
     def __init__(self, ctx: GraphCtx, app: MiningApp, backend,
@@ -141,63 +145,58 @@ class _PhaseOps:
         self.fuse_filter = fuse_filter
         self.materialize = materialize_fn or materialize
         be = backend
+
+        def bind(name, fn, static=()):
+            if jit:
+                fn = _obs_op(name, be.name,
+                             jax.jit(fn, static_argnames=static))
+            return functools.partial(fn, ctx)
+
         if app.kind == "vertex":
-            def inspect(emb, n, st, *, cand_cap):
-                return be.inspect_vertex(ctx, app, emb, n, st, cand_cap)
+            def inspect(c, emb, n, st, *, cand_cap):
+                return be.inspect_vertex(c, app, emb, n, st, cand_cap)
 
-            def bound(emb, n, st):
-                return be.candidate_bound_vertex(ctx, app, emb, n, st)
+            def bound(c, emb, n, st):
+                return be.candidate_bound_vertex(c, app, emb, n, st)
 
-            def extend(emb, n, st, *, cand_cap, out_cap):
+            def extend(c, emb, n, st, *, cand_cap, out_cap):
                 # fused extend+filter+compact with counts: the one
                 # enumeration per level (no separate inspection on replay)
-                return be.extend_pruned(ctx, app, emb, n, st, cand_cap,
+                return be.extend_pruned(c, app, emb, n, st, cand_cap,
                                         out_cap, fuse_filter=fuse_filter)
 
-            def reduce(emb, n, st):
-                return be.reduce_count(ctx, app, emb, n, st)
+            def reduce(c, emb, n, st):
+                return be.reduce_count(c, app, emb, n, st)
 
-            if jit:
-                bn = be.name
-                inspect = _obs_op("inspect_vertex", bn, jax.jit(
-                    inspect, static_argnames=("cand_cap",)))
-                bound = _obs_op("bound_vertex", bn, jax.jit(bound))
-                extend = _obs_op("extend_pruned", bn, jax.jit(
-                    extend, static_argnames=("cand_cap", "out_cap")))
-                reduce = _obs_op("reduce_count", bn, jax.jit(reduce))
-            self._inspect, self._bound = inspect, bound
-            self._extend, self._reduce = extend, reduce
+            self._inspect = bind("inspect_vertex", inspect, ("cand_cap",))
+            self._bound = bind("bound_vertex", bound)
+            self._extend = bind("extend_pruned", extend,
+                                ("cand_cap", "out_cap"))
+            self._reduce = bind("reduce_count", reduce)
         else:
-            def bound_e(v0, vid, his, n):
-                return be.candidate_bound_edge(ctx, app, v0, vid, his, n)
+            def bound_e(c, v0, vid, his, n):
+                return be.candidate_bound_edge(c, app, v0, vid, his, n)
 
-            def inspect_e(v0, vid, his, eid, n, *, cand_cap):
-                return be.inspect_edge(ctx, app, v0, vid, his, eid, n,
+            def inspect_e(c, v0, vid, his, eid, n, *, cand_cap):
+                return be.inspect_edge(c, app, v0, vid, his, eid, n,
                                        cand_cap)
 
-            def extend_e(v0, vid, his, eid, n, *, cand_cap, out_cap):
-                return be.extend_edge(ctx, app, v0, vid, his, eid, n,
+            def extend_e(c, v0, vid, his, eid, n, *, cand_cap, out_cap):
+                return be.extend_edge(c, app, v0, vid, his, eid, n,
                                       cand_cap, out_cap)
 
-            def reduce_e(lvls):
-                return be.reduce_domain(ctx, app, lvls)
+            def reduce_e(c, lvls):
+                return be.reduce_domain(c, app, lvls)
 
-            def filter_e(lvls, keep, *, out_cap):
+            def filter_e(c, lvls, keep, *, out_cap):
                 return be.filter_levels(lvls, keep, out_cap)
 
-            if jit:
-                bn = be.name
-                bound_e = _obs_op("bound_edge", bn, jax.jit(bound_e))
-                inspect_e = _obs_op("inspect_edge", bn, jax.jit(
-                    inspect_e, static_argnames=("cand_cap",)))
-                extend_e = _obs_op("extend_edge", bn, jax.jit(
-                    extend_e, static_argnames=("cand_cap", "out_cap")))
-                reduce_e = _obs_op("reduce_domain", bn, jax.jit(reduce_e))
-                filter_e = _obs_op("filter_levels", bn, jax.jit(
-                    filter_e, static_argnames=("out_cap",)))
-            self._bound_e, self._inspect_e = bound_e, inspect_e
-            self._extend_e, self._reduce_e = extend_e, reduce_e
-            self._filter_e = filter_e
+            self._bound_e = bind("bound_edge", bound_e)
+            self._inspect_e = bind("inspect_edge", inspect_e, ("cand_cap",))
+            self._extend_e = bind("extend_edge", extend_e,
+                                  ("cand_cap", "out_cap"))
+            self._reduce_e = bind("reduce_domain", reduce_e)
+            self._filter_e = bind("filter_levels", filter_e, ("out_cap",))
 
     def reduce_e(self, levels, axis_names: tuple[str, ...] = ()):
         """Domain reduce; with mesh axes, the collective (sharded) variant."""
@@ -507,6 +506,7 @@ class Miner:
         self.app = app
         self.backend = get_backend(backend if backend is not None
                                    else app.backend)
+        self.backend.check_device()
         # locality-aware layout: relabel *before* DAG orientation so the
         # oriented CSR, the packed adjacency core, and the level-0
         # worklist all live in the permuted id space; every mined
@@ -845,6 +845,7 @@ def bounded_mine_vertex(ctx: GraphCtx, app: MiningApp,
     all phase ops resolve through the backend registry.
     """
     be = get_backend(backend if backend is not None else app.backend)
+    be.check_device()
     ops = _PhaseOps(ctx, app, be)
     pipe = _VertexPipeline(ops, src, dst, n_valid)
     policy = PlanCapPolicy(MiningPlan(kind="vertex", caps=tuple(caps)))
@@ -875,6 +876,7 @@ def bounded_mine_edge(ctx: GraphCtx, app: MiningApp,
     all devices' embeddings (the paper's global support sync).
     """
     be = get_backend(backend if backend is not None else app.backend)
+    be.check_device()
     ops = _PhaseOps(ctx, app, be)
     pipe = _EdgePipeline(ops, src=src, dst=dst, eid=eid, n=n_valid,
                          axis_names=axis_names)
@@ -882,6 +884,57 @@ def bounded_mine_edge(ctx: GraphCtx, app: MiningApp,
                                       filter_caps=tuple(filter_caps)))
     run_level_loop(pipe, policy)
     return pipe.bounded_result(policy)
+
+
+def sharded_program(app: MiningApp, mesh,
+                    caps: tuple[tuple[int, int], ...],
+                    axis_names: tuple[str, ...] = ("data",),
+                    backend: BackendSpec = None,
+                    filter_caps: Optional[tuple[int, ...]] = None):
+    """The jitted ``shard_map`` program behind :func:`mine_sharded`.
+
+    Vertex apps: ``(ctx, src[n_dev, cap0], dst[n_dev, cap0], n[n_dev]) ->
+    (count, p_map, overflowed)``; edge apps take ``eid`` after ``dst`` and
+    return ``(codes, supports, overflowed)``.  Row i of each stacked
+    worklist array goes to device i; the graph context is an argument
+    replicated on every device (``PartitionSpec()``), so the program
+    carries no graph-sized constant.
+    """
+    from jax.sharding import PartitionSpec as PSpec
+
+    be = get_backend(backend if backend is not None else app.backend)
+    spec, rep = PSpec(axis_names), PSpec()
+    if app.kind == "edge":
+        if filter_caps is None:
+            raise ValueError("sharded FSM needs filter_caps (support-filter "
+                             "output capacities per level)")
+
+        def local_e(c, src_blk, dst_blk, eid_blk, n_blk):
+            codes, sup, ovf = bounded_mine_edge(
+                c, app, src_blk[0], dst_blk[0], eid_blk[0], n_blk[0],
+                caps, tuple(filter_caps), backend=be,
+                axis_names=axis_names)
+            for ax in axis_names:
+                ovf = jax.lax.pmax(ovf.astype(jnp.int32), ax).astype(bool)
+            return codes, sup, ovf
+
+        return jax.jit(jax.shard_map(
+            local_e, mesh=mesh, in_specs=(rep,) + (spec,) * 4,
+            out_specs=(rep, rep, rep), check_vma=False))
+
+    def local(c, src_blk, dst_blk, n_blk):
+        cnt, p_map, ovf = bounded_mine_vertex(c, app, src_blk[0],
+                                              dst_blk[0], n_blk[0], caps,
+                                              backend=be)
+        for ax in axis_names:
+            cnt = jax.lax.psum(cnt, ax)
+            p_map = jax.lax.psum(p_map, ax)
+            ovf = jax.lax.pmax(ovf.astype(jnp.int32), ax).astype(bool)
+        return cnt, p_map, ovf
+
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(rep, spec, spec, spec),
+        out_specs=(rep, rep, rep), check_vma=False))
 
 
 def mine_sharded(graph: CSRGraph, app: MiningApp, mesh,
@@ -908,68 +961,53 @@ def mine_sharded(graph: CSRGraph, app: MiningApp, mesh,
     vertex apps -> (count, p_map, overflowed);
     edge apps   -> (count, codes, supports, overflowed).
     """
-    from jax.sharding import PartitionSpec as PSpec
-    from jax.experimental.shard_map import shard_map
+    program = sharded_program(app, mesh, caps, axis_names, backend,
+                              filter_caps)
+    args = sharded_inputs(graph, app, mesh, axis_names, backend, relabel)
+    return sharded_results(app, program(*args))
 
-    if app.kind == "edge" and filter_caps is None:
-        raise ValueError("sharded FSM needs filter_caps (support-filter "
-                         "output capacities per level)")
+
+def sharded_inputs(graph: CSRGraph, app: MiningApp, mesh,
+                   axis_names: tuple[str, ...] = ("data",),
+                   backend: BackendSpec = None,
+                   relabel: bool | str = False) -> tuple:
+    """The arguments of :func:`sharded_program`, placed on ``mesh``.
+
+    Returns ``(ctx, *worklist, counts)``: the graph context replicated on
+    every device, and the level-0 worklist cut into one block per device,
+    row i of each stacked array (and ``counts[i]``) on device i.
+    """
+    from jax.sharding import NamedSharding, PartitionSpec as PSpec
+
     # reuse ctx preprocessing (DAG orient, packs, uids) + optional relabel
     miner = Miner(graph, app, backend=backend, relabel=relabel)
     ctx = miner.ctx
     n_dev = int(np.prod([mesh.shape[a] for a in axis_names]))
-    spec = PSpec(axis_names)
-
     if app.kind == "edge":
         m = ctx.n_uedges
-        per_dev = -(-m // n_dev)
-        cap0 = bucket_pow2(per_dev)
-        blocks = make_blocks(m, per_dev, count=n_dev)
-        counts = jnp.asarray([b.n for b in blocks], dtype=jnp.int32)
-        src_b, dst_b, eid_b = stack_blocks(
-            (np.asarray(ctx.usrc), np.asarray(ctx.udst),
-             np.arange(m, dtype=np.int32)), blocks, cap0)
+        columns = (np.asarray(ctx.usrc), np.asarray(ctx.udst),
+                   np.arange(m, dtype=np.int32))
+    else:
+        columns = tuple(np.asarray(a) for a in miner.init_edges())
+        m = int(columns[0].shape[0])
+    per_dev = -(-m // n_dev)
+    blocks = make_blocks(m, per_dev, count=n_dev)
+    counts = np.asarray([b.n for b in blocks], dtype=np.int32)
+    stacked = stack_blocks(columns, blocks, bucket_pow2(per_dev))
+    ctx = jax.device_put(ctx, NamedSharding(mesh, PSpec()))
+    worklist = jax.device_put(stacked + (counts,),
+                              NamedSharding(mesh, PSpec(axis_names)))
+    return (ctx, *worklist)
 
-        def local_e(src_blk, dst_blk, eid_blk, n_blk):
-            codes, sup, ovf = bounded_mine_edge(
-                ctx, app, src_blk[0], dst_blk[0], eid_blk[0], n_blk[0],
-                caps, tuple(filter_caps), backend=miner.backend,
-                axis_names=axis_names)
-            for ax in axis_names:
-                ovf = jax.lax.pmax(ovf.astype(jnp.int32), ax).astype(bool)
-            return codes, sup, ovf
 
-        fn = shard_map(local_e, mesh=mesh, in_specs=(spec,) * 4,
-                       out_specs=(PSpec(), PSpec(), PSpec()),
-                       check_rep=False)
-        with mesh:
-            codes, sup, ovf = jax.jit(fn)(src_b, dst_b, eid_b, counts)
+def sharded_results(app: MiningApp, out) -> tuple:
+    """:func:`sharded_program`'s outputs as :func:`mine_sharded` returns
+    them (host values)."""
+    if app.kind == "edge":
+        codes, sup, ovf = out
         codes, sup = np.asarray(codes), np.asarray(sup)
         cnt = int(((sup >= app.min_support)
                    & (codes != _INT_MAX)).sum())
         return cnt, codes, sup, bool(ovf)
-
-    src, dst = miner.init_edges()
-    m = int(src.shape[0])
-    per_dev = -(-m // n_dev)
-    cap0 = bucket_pow2(per_dev)
-    blocks = make_blocks(m, per_dev, count=n_dev)
-    counts = jnp.asarray([b.n for b in blocks], dtype=jnp.int32)
-    src_b, dst_b = stack_blocks((np.asarray(src), np.asarray(dst)),
-                                blocks, cap0)
-
-    def local(src_blk, dst_blk, n_blk):
-        cnt, p_map, ovf = bounded_mine_vertex(ctx, app, src_blk[0],
-                                              dst_blk[0], n_blk[0], caps,
-                                              backend=miner.backend)
-        for ax in axis_names:
-            cnt = jax.lax.psum(cnt, ax)
-            p_map = jax.lax.psum(p_map, ax)
-            ovf = jax.lax.pmax(ovf.astype(jnp.int32), ax).astype(bool)
-        return cnt, p_map, ovf
-
-    fn = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=(PSpec(), PSpec(), PSpec()), check_rep=False)
-    with mesh:
-        cnt, p_map, ovf = jax.jit(fn)(src_b, dst_b, counts)
+    cnt, p_map, ovf = out
     return int(cnt), np.asarray(p_map), bool(ovf)

@@ -81,6 +81,10 @@ class PhaseBackend:
     compaction_passes: int = 0
     grid_contract: str = "any"
 
+    def check_device(self) -> None:
+        """Raise, before anything is traced, if this backend cannot run
+        on the current JAX backend.  Pure-XLA backends always can."""
+
     def capabilities(self, app: Optional[MiningApp] = None) -> dict:
         """Which ops actually run fused under this backend.
 

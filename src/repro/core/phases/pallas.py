@@ -48,7 +48,7 @@ from repro.core.phases.reference import (ReferenceBackend, edge_vertex_slots,
                                          vertex_ext_degrees)
 from repro.kernels.extend_fused import (fused_extend, fused_extend_edge,
                                         fused_extend_pruned)
-from repro.kernels.runtime import resolve_interpret
+from repro.kernels.runtime import require_lowerable, resolve_interpret
 
 
 class PallasExtendBackend(ReferenceBackend):
@@ -67,8 +67,15 @@ class PallasExtendBackend(ReferenceBackend):
         self.interpret = interpret
         self.block_c = block_c
 
+    # the Pallas kernels this backend launches (keys of TPU_REFUSALS)
+    kernels = ("fused_extend", "fused_extend_pruned", "fused_extend_edge")
+
     def _use_interpret(self) -> bool:
         return resolve_interpret(self.interpret)
+
+    def check_device(self) -> None:
+        require_lowerable(self.kernels, f"backend={self.name!r}",
+                          self._use_interpret())
 
     # -- capability report -------------------------------------------------
 

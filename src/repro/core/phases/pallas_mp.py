@@ -45,6 +45,7 @@ class PallasMPBackend(PallasExtendBackend):
     grid_contract = "concurrent"
 
     _pruned_kernel = staticmethod(fused_extend_pruned_mp)
+    kernels = ("fused_extend", "fused_extend_pruned_mp", "fused_extend_edge")
 
     def extend_pruned(self, ctx, app, emb, n_valid, state, cand_cap,
                       out_cap, fuse_filter=True):

@@ -5,9 +5,11 @@ EXTEND is the inspection-execution candidate generation of paper §5.3:
   1. *inspection*: per parent embedding, count candidate extensions
      (degree gather, masked by ``toExtend``) and prefix-sum to obtain each
      parent's output offset;
-  2. *expansion*: each output slot finds its (parent, rank) by binary search
-     on the offsets (``expand_ragged``) and gathers its candidate vertex
-     from CSR;
+  2. *expansion*: each output slot finds its (parent, rank) — by a
+     prefix sum over marks at the parents' first slots in the chunked
+     vertex extend (:func:`extend_vertex_chunked`), by binary search on
+     the offsets (``expand_ragged``) elsewhere — and gathers its
+     candidate vertex from CSR;
   3. *write*: ``toAdd`` is evaluated on candidates *before* they are
      written (the paper's loop fusion / materialization avoidance, §5.2),
      and survivors are compacted into the next SoA level by a prefix-sum
@@ -40,7 +42,8 @@ from repro.core.api import (GraphCtx, MiningApp, is_auto_canonical_edge,
 from repro.core.embedding_list import EmbeddingLevel, materialize_edges
 from repro.core.phases.base import PhaseBackend
 from repro.core import pattern as P
-from repro.sparse.ops import compact_mask, expand_ragged
+from repro.sparse.intersect import binary_contains
+from repro.sparse.ops import compact_mask, expand_ragged, scatter_sorted
 
 _INT_MAX = np.int32(np.iinfo(np.int32).max)
 
@@ -59,6 +62,14 @@ def vertex_ext_degrees(ctx: GraphCtx, app: MiningApp, emb: jnp.ndarray,
     needs — the multi-pattern trie's dead branches never generate
     candidates at all.
     """
+    ext = vertex_ext_mask(ctx, app, emb, n_valid, state)
+    return jnp.where(ext, ctx.degree(emb), 0)          # [cap, k]
+
+
+def vertex_ext_mask(ctx: GraphCtx, app: MiningApp, emb: jnp.ndarray,
+                    n_valid: jnp.ndarray,
+                    state: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """bool[cap, k]: which (parent, slot) pairs extend (``toExtend``)."""
     cap, k = emb.shape
     valid = jnp.arange(cap, dtype=jnp.int32) < n_valid
     if app.to_extend_state is not None and state is not None:
@@ -67,8 +78,7 @@ def vertex_ext_degrees(ctx: GraphCtx, app: MiningApp, emb: jnp.ndarray,
         ext = app.to_extend(ctx, emb)
     else:
         ext = jnp.ones((cap, k), bool)
-    ext = ext & valid[:, None]
-    return jnp.where(ext, ctx.degree(emb), 0)          # [cap, k]
+    return ext & valid[:, None]
 
 
 def vertex_add_mask(ctx: GraphCtx, app: MiningApp, emb: jnp.ndarray,
@@ -83,8 +93,16 @@ def vertex_add_mask(ctx: GraphCtx, app: MiningApp, emb: jnp.ndarray,
     hook path is taken: ``app.to_add_bits`` if provided, else the
     connectivity-bit variant of the automorphism-canonical test.
     """
-    parent_emb = emb[row_c]
     parent_state = None if state is None else state[row_c]
+    return _add_mask(ctx, app, emb[row_c], u, src_slot, parent_state, live,
+                     conn)
+
+
+def _add_mask(ctx: GraphCtx, app: MiningApp, parent_emb: jnp.ndarray,
+              u: jnp.ndarray, src_slot: jnp.ndarray,
+              parent_state: Optional[jnp.ndarray], live: jnp.ndarray,
+              conn: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """:func:`vertex_add_mask` on the candidates' parent rows."""
     if conn is not None and app.to_add_bits is not None:
         add = app.to_add_bits(ctx, parent_emb, u, src_slot, parent_state,
                               conn)
@@ -98,6 +116,14 @@ def vertex_add_mask(ctx: GraphCtx, app: MiningApp, emb: jnp.ndarray,
         # canonical test's emb_j-in-N(u) probes — re-probe the CSR instead.
         add = is_auto_canonical_vertex(ctx, parent_emb, u, src_slot)
     return add & live
+
+
+def parent_columns(emb: jnp.ndarray, row_c: jnp.ndarray
+                   ) -> tuple[jnp.ndarray, ...]:
+    """The parent embedding's k columns at candidate scale, as k 1-D
+    gathers.  A row gather ``emb[row_c]`` would make an ``[N, k]`` array,
+    which the TPU tiles to 128 lanes: 512 bytes per candidate."""
+    return tuple(emb[:, j][row_c] for j in range(emb.shape[1]))
 
 
 def apply_kernel_predicate(ctx: GraphCtx, pred, emb: jnp.ndarray,
@@ -115,10 +141,8 @@ def apply_kernel_predicate(ctx: GraphCtx, pred, emb: jnp.ndarray,
     label stage (zeros when the graph is unlabeled) — again bitwise
     equal by construction.
     """
-    k = emb.shape[1]
-    parent = emb[row_c]
-    emb_cols = tuple(parent[:, j] for j in range(k))
-    conn = tuple(ctx.is_connected(parent[:, j], u) for j in range(k))
+    emb_cols = parent_columns(emb, row_c)
+    conn = tuple(ctx.is_connected(c, u) for c in emb_cols)
     st = (jnp.zeros(u.shape, jnp.int32) if state is None
           else state[row_c])
     if getattr(pred, "needs_labels", False):
@@ -143,10 +167,8 @@ def apply_state_kernel(ctx: GraphCtx, upd, emb: jnp.ndarray,
     equal.  Non-surviving candidates' outputs are dropped by the
     compaction gather, so no masking is needed here.
     """
-    k = emb.shape[1]
-    parent = emb[row_c]
-    emb_cols = tuple(parent[:, j] for j in range(k))
-    conn = tuple(ctx.is_connected(parent[:, j], u) for j in range(k))
+    emb_cols = parent_columns(emb, row_c)
+    conn = tuple(ctx.is_connected(c, u) for c in emb_cols)
     st = (jnp.zeros(u.shape, jnp.int32) if state is None
           else state[row_c])
     return upd(emb_cols, u, src_slot, st, conn).astype(jnp.int32)
@@ -182,7 +204,7 @@ def _vertex_candidates(ctx: GraphCtx, app: MiningApp, emb: jnp.ndarray,
     col = slot_parent % k
     live = slot_parent >= 0
     row_c = jnp.clip(row, 0, cap - 1)
-    v = emb[row_c, jnp.clip(col, 0, k - 1)]
+    v = emb.reshape(-1)[row_c * k + jnp.clip(col, 0, k - 1)]
     ptr = ctx.row_ptr[jnp.clip(v, 0, ctx.n_vertices - 1)] + rank
     # zero-edge graphs: col_idx is empty and a gather from it is invalid
     col_idx = ctx.col_idx if ctx.n_edges else jnp.zeros(1, ctx.col_idx.dtype)
@@ -253,6 +275,164 @@ def extend_vertex(ctx: GraphCtx, app: MiningApp, emb: jnp.ndarray,
     row, u, _, add, _ = _vertex_candidates(ctx, app, emb, n_valid, state,
                                            cand_cap)
     return finish_extend_vertex(emb, row, u, add, out_cap, fuse_filter)
+
+
+# Candidate slots one step of :func:`extend_vertex_chunked` enumerates: a
+# level takes ceil(n_candidates / CHUNK_SLOTS) steps whatever its planned
+# capacity, so capacity padding costs no gathers.
+CHUNK_SLOTS = 1 << 16
+
+
+def _forward_fill(pos: jnp.ndarray, vals: list, size: int) -> list:
+    """``out[r][i] = vals[r][j]`` for the last ``j`` with ``pos[j] <= i``.
+
+    ``pos`` must start at 0 and not decrease; entries at or past ``size``
+    are dropped.  Each value is scattered as its difference to the
+    previous one and prefix-summed, which is exact in int32 (wraparound
+    cancels) and gathers nothing.
+    """
+    marks = jnp.zeros((size,), jnp.int32)
+    out = []
+    for v in vals:
+        deltas = jnp.diff(v, prepend=jnp.zeros_like(v[:1]))
+        out.append(jnp.cumsum(scatter_sorted(marks, pos, deltas)))
+    return out
+
+
+def _pick(col: jnp.ndarray, options) -> jnp.ndarray:
+    """``options[col]`` elementwise, for a short static list of arrays."""
+    out = options[0]
+    for j in range(1, len(options)):
+        out = jnp.where(col == j, options[j], out)
+    return out
+
+
+def extend_vertex_chunked(ctx: GraphCtx, app: MiningApp, emb: jnp.ndarray,
+                          n_valid: jnp.ndarray,
+                          state: Optional[jnp.ndarray], cand_cap: int,
+                          out_cap: int):
+    """:func:`extend_vertex` (fused filter, plus the state update) in a
+    loop over chunks of candidate slots; bitwise the same level.
+
+    The loop runs over the level's real candidates only (at most
+    ``cand_cap`` of them), so the padding of an estimated capacity costs
+    nothing.  Within a chunk every per-parent quantity (the row's slot
+    offsets per column, its embedding columns and their CSR ranges, its
+    state) reaches the slots by :func:`_forward_fill` instead of a gather
+    per slot; what is left per candidate is the CSR gather of the
+    candidate itself and the connectivity probes.  Survivors are written
+    at their running offset, in slot order, exactly as the one-shot
+    compaction writes them.  Everything is kept in 1-D columns: on the
+    TPU, flattening an ``[N, k]`` array costs tens of seconds of compile
+    time.  Returns ``(level, new_emb, n_candidates)``.
+    """
+    emb, state = _pad_empty_frontier(emb, state)
+    cap, k = emb.shape
+    chunk = min(cand_cap, CHUNK_SLOTS)
+    ext = vertex_ext_mask(ctx, app, emb, n_valid, state)
+    raw = [emb[:, j] for j in range(k)]
+    vtx = [jnp.clip(e, 0, ctx.n_vertices - 1) for e in raw]
+    lo = [ctx.row_ptr[v] for v in vtx]
+    hi = [ctx.row_ptr[v + 1] for v in vtx]
+    cum, acc = [], jnp.zeros((cap,), jnp.int32)
+    for j in range(k):                # slots of the row's columns <= j
+        acc = acc + jnp.where(ext[:, j], hi[j] - lo[j], 0)
+        cum.append(acc)
+    row_offsets = jnp.cumsum(acc)
+    total = row_offsets[-1].astype(jnp.int32)
+    limit = jnp.minimum(total, jnp.int32(cand_cap))
+
+    # per-row table over the rows with candidates, in slot order
+    rid, n_rows = compact_mask(acc > 0, cap)
+    pred = resolve_kernel_predicate(app, k)
+    upd = resolve_state_kernel(app, k)
+    csr_conn = ctx.packed is None and ctx.search == "binary"
+    with_labels = pred is not None and getattr(pred, "needs_labels", False)
+    columns = [row_offsets - acc, jnp.arange(cap, dtype=jnp.int32)]
+    columns += cum[:-1] + lo + raw
+    if csr_conn:
+        columns += hi
+    if state is not None:
+        columns.append(state)
+    labels = (ctx.labels if ctx.labels is not None
+              else jnp.zeros((1,), jnp.int32))
+    if with_labels:
+        columns += [labels[jnp.clip(e, 0, labels.shape[0] - 1)]
+                    for e in raw]
+    live_r = jnp.arange(cap, dtype=jnp.int32) < n_rows
+    table = [c.astype(jnp.int32)[rid] for c in columns]
+    table[0] = jnp.where(live_r, table[0], _INT_MAX)
+    table = jnp.stack(table)
+    # a window of chunk + 1 rows covers any chunk: pad past the end
+    pad = jnp.zeros((table.shape[0], chunk + 1), jnp.int32)
+    table = jnp.concatenate([table, pad.at[0].set(_INT_MAX)], axis=1)
+
+    col_idx = ctx.col_idx if ctx.n_edges else jnp.zeros(1, ctx.col_idx.dtype)
+    last_edge = max(ctx.n_edges - 1, 0)
+    lane = jnp.arange(chunk, dtype=jnp.int32)
+
+    def step(carry):
+        c, n_surv, out_vid, out_idx, out_st = carry
+        base = c * chunk
+        q0 = jnp.searchsorted(table[0], base, side="right") - 1
+        win = jax.lax.dynamic_slice_in_dim(table, q0, chunk + 1, axis=1)
+        f = _forward_fill(jnp.maximum(win[0] - base, 0), list(win), chunk)
+        slot = base + lane
+        live = slot < limit
+        off = slot - f[0]                       # slot within its row
+        row_c = jnp.clip(f[1], 0, cap - 1)
+        cum_f, f = f[2:1 + k], f[1 + k:]
+        lo_f, emb_cols, f = f[:k], tuple(f[k:2 * k]), f[2 * k:]
+        col = sum((off >= b).astype(jnp.int32) for b in cum_f)
+        col = jnp.asarray(col, jnp.int32)
+        first = _pick(col, [jnp.zeros_like(off)] + cum_f)
+        u = col_idx[jnp.clip(_pick(col, lo_f) + off - first, 0, last_edge)]
+        u = jnp.where(live, u, -1)
+        if csr_conn:
+            hi_f, f = f[:k], f[k:]
+            conn = tuple(binary_contains(col_idx, lo_f[j], hi_f[j], u,
+                                         ctx.n_steps)
+                         & (emb_cols[j] >= 0) & (u >= 0)
+                         for j in range(k))
+        else:
+            conn = tuple(ctx.is_connected(e, u) for e in emb_cols)
+        st = None
+        if state is not None:
+            st, f = f[0], f[1:]
+        st0 = jnp.zeros(u.shape, jnp.int32) if st is None else st
+        if pred is None:
+            add = _add_mask(ctx, app, jnp.stack(emb_cols, axis=1), u, col,
+                            st, live)
+        elif with_labels:
+            lab_u = labels[jnp.clip(u, 0, labels.shape[0] - 1)]
+            add = pred(emb_cols, u, col, st0, conn, tuple(f[:k]),
+                       lab_u) & live
+        else:
+            add = pred(emb_cols, u, col, st0, conn) & live
+        # survivors land at their running offset; every other lane adds
+        # 0 at the next survivor's position (sorted, collision-safe)
+        keep = add.astype(jnp.int32)
+        at = n_surv + jnp.cumsum(keep) - keep
+        out_vid = scatter_sorted(out_vid, at, (u + 1) * keep)
+        out_idx = scatter_sorted(out_idx, at, row_c * keep)
+        if upd is not None:
+            new_st = upd(emb_cols, u, col, st0, conn).astype(jnp.int32)
+            out_st = scatter_sorted(out_st, at, new_st * keep)
+        return c + 1, n_surv + jnp.sum(keep), out_vid, out_idx, out_st
+
+    zeros = jnp.zeros((out_cap,), jnp.int32)
+    carry = (jnp.int32(0), jnp.int32(0), zeros, zeros, zeros)
+    if chunk == cand_cap:       # one chunk covers the level; an empty
+        carry = step(carry)     # one writes nothing
+    else:
+        carry = jax.lax.while_loop(lambda c: c[0] * chunk < limit, step,
+                                   carry)
+    _, n_new, vid, idx, st = carry
+    vid = vid - 1                             # vid is stored plus one
+    level = EmbeddingLevel(vid=vid, idx=idx, n=n_new,
+                           state=None if upd is None else st)
+    new_emb = jnp.concatenate([emb[idx], vid[:, None]], axis=1)
+    return level, new_emb, total
 
 
 # ---------------------------------------------------------------------------
@@ -709,7 +889,17 @@ class ReferenceBackend(PhaseBackend):
     def candidate_bound_vertex(self, ctx, app, emb, n_valid, state=None):
         return candidate_bound_vertex(ctx, app, emb, n_valid, state)
 
+    def _enumerates_in_xla(self) -> bool:
+        """True unless a subclass swapped the enumeration step: the
+        chunked extend is this class's own enumeration."""
+        return (type(self)._vertex_candidates
+                is ReferenceBackend._vertex_candidates)
+
     def inspect_vertex(self, ctx, app, emb, n_valid, state, cand_cap):
+        if self._enumerates_in_xla():
+            level, _, total = extend_vertex_chunked(ctx, app, emb, n_valid,
+                                                    state, cand_cap, 1)
+            return total, level.n
         _, _, _, add, total = self._vertex_candidates(ctx, app, emb,
                                                       n_valid, state,
                                                       cand_cap)
@@ -725,6 +915,9 @@ class ReferenceBackend(PhaseBackend):
     def extend_pruned(self, ctx, app, emb, n_valid, state, cand_cap,
                       out_cap, fuse_filter=True):
         self.note_op("extend_pruned", mode="xla")
+        if fuse_filter and self._enumerates_in_xla():
+            return extend_vertex_chunked(ctx, app, emb, n_valid, state,
+                                         cand_cap, out_cap)
         emb, state = _pad_empty_frontier(emb, state)
         row, u, src_slot, add, total = self._vertex_candidates(
             ctx, app, emb, n_valid, state, cand_cap)

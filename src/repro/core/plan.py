@@ -37,6 +37,7 @@ shared level loop under a :class:`PlanCapPolicy`.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import json
 import os
@@ -638,13 +639,16 @@ def _estimate_plan(miner, cap0, sample_size, safety_factor, seed
         caps=((SAMPLE_CAND_CAP, SAMPLE_OUT_CAP),) * n_levels,
         filter_caps=((SAMPLE_OUT_CAP,) * (n_levels + 1)
                      if needs_filter else ()))
-    ops = E._PhaseOps(ctx, samp_app, get_backend("reference"),
-                      fuse_filter=miner.fuse_filter,
-                      materialize_fn=miner._materialize)
+    reference = get_backend("reference")
+
+    def ops_for(c):
+        return E._PhaseOps(c, samp_app, reference,
+                           fuse_filter=miner.fuse_filter,
+                           materialize_fn=miner._materialize)
 
     if app.kind == "edge":
-        def probe(s, d, e, n):
-            pipe = E._EdgePipeline(ops, src=s, dst=d, eid=e, n=n)
+        def probe(c, s, d, e, n):
+            pipe = E._EdgePipeline(ops_for(c), src=s, dst=d, eid=e, n=n)
             policy = _ProbePolicy(probe_plan)
             E.run_level_loop(pipe, policy)
             return (tuple(policy.n_cand), tuple(policy.n_surv),
@@ -652,15 +656,15 @@ def _estimate_plan(miner, cap0, sample_size, safety_factor, seed
         args = (ctx.usrc[jnp.asarray(idx)], ctx.udst[jnp.asarray(idx)],
                 jnp.asarray(idx, jnp.int32), jnp.int32(n_sample))
     else:
-        def probe(s, d, n):
-            pipe = E._VertexPipeline(ops, s, d, n)
+        def probe(c, s, d, n):
+            pipe = E._VertexPipeline(ops_for(c), s, d, n)
             policy = _ProbePolicy(probe_plan)
             E.run_level_loop(pipe, policy)
             return (tuple(policy.n_cand), tuple(policy.n_surv),
                     tuple(policy.n_keep))
         args = (jnp.asarray(np.asarray(src)[idx]),
                 jnp.asarray(np.asarray(dst)[idx]), jnp.int32(n_sample))
-    n_cand, n_surv, n_keep = jax.jit(probe)(*args)
+    n_cand, n_surv, n_keep = jax.jit(probe)(ctx, *args)
     n_cand = [int(x) for x in n_cand]
     n_surv = [int(x) for x in n_surv]
     n_keep = [int(x) for x in n_keep]
@@ -702,6 +706,37 @@ def _estimate_plan(miner, cap0, sample_size, safety_factor, seed
 
 # ---------------------------------------------------------------------------
 # The executor
+
+
+def plan_program(plan: MiningPlan, app, backend, fuse_filter: bool = True,
+                 materialize_fn=None):
+    """The jitted whole-run program that replays ``plan``.
+
+    Vertex plans: ``(ctx, src, dst, n_valid) -> (count, p_map,
+    overflowed)``; edge plans: ``(ctx, src, dst, eid, n_valid) -> (codes,
+    supports, overflowed)``.  The graph context is the first argument —
+    an input buffer of the executable, never a constant folded into it.
+    """
+    from repro.core import engine as E
+
+    def ops_for(ctx):
+        return E._PhaseOps(ctx, app, backend, fuse_filter=fuse_filter,
+                           materialize_fn=materialize_fn)
+
+    if plan.kind == "vertex":
+        def fn(ctx, src, dst, n_valid):
+            pipe = E._VertexPipeline(ops_for(ctx), src, dst, n_valid)
+            policy = PlanCapPolicy(plan)
+            E.run_level_loop(pipe, policy)
+            return pipe.bounded_result(policy)
+    else:
+        def fn(ctx, src, dst, eid, n_valid):
+            pipe = E._EdgePipeline(ops_for(ctx), src=src, dst=dst, eid=eid,
+                                   n=n_valid)
+            policy = PlanCapPolicy(plan)
+            E.run_level_loop(pipe, policy)
+            return pipe.bounded_result(policy)
+    return jax.jit(fn)
 
 
 class MiningExecutor:
@@ -816,26 +851,11 @@ class MiningExecutor:
         return fn
 
     def _build(self, plan: MiningPlan):
-        from repro.core import engine as E
-        ops = E._PhaseOps(self.miner.ctx, self.miner.app,
-                          self.miner.backend,
-                          fuse_filter=self.miner.fuse_filter,
-                          materialize_fn=self.miner._materialize)
-
-        if self.kind == "vertex":
-            def fn(src, dst, n_valid):
-                pipe = E._VertexPipeline(ops, src, dst, n_valid)
-                policy = PlanCapPolicy(plan)
-                E.run_level_loop(pipe, policy)
-                return pipe.bounded_result(policy)
-        else:
-            def fn(src, dst, eid, n_valid):
-                pipe = E._EdgePipeline(ops, src=src, dst=dst, eid=eid,
-                                       n=n_valid)
-                policy = PlanCapPolicy(plan)
-                E.run_level_loop(pipe, policy)
-                return pipe.bounded_result(policy)
-        return jax.jit(fn)
+        miner = self.miner
+        program = plan_program(plan, miner.app, miner.backend,
+                               fuse_filter=miner.fuse_filter,
+                               materialize_fn=miner._materialize)
+        return functools.partial(program, miner.ctx)
 
     # -- execution ----------------------------------------------------------
 

@@ -11,8 +11,10 @@ mining/jit code only ever sees the dense arrays.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -56,6 +58,9 @@ class CSRGraph:
         return jnp.asarray(src_np[keep]), jnp.asarray(dst_np[keep])
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["words", "row_slot"],
+                   meta_fields=["n_words", "full", "n_cols"])
 @dataclasses.dataclass(frozen=True)
 class PackedGraph:
     """Bit-packed adjacency bitmap rows (u32 words) for O(1) connectivity.

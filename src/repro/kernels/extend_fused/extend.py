@@ -548,12 +548,12 @@ def _mp_scatter_kernel(offsets_ref, starts_ref, emb_ref, vlo_ref, vhi_ref,
     comp_u = _take_tile(u, sel).reshape(block_c)
     base = bases_ref[i]
     bw = jnp.minimum(base, out_len - block_c)
-    idx = (pl.dslice(bw, block_c),)
-    pl.store(row_ref, idx, comp_row, mask=lane_live)
-    pl.store(u_ref, idx, comp_u, mask=lane_live)
+    win = pl.dslice(bw, block_c)
+    pltpu.store(row_ref.at[win], comp_row, mask=lane_live)
+    pltpu.store(u_ref.at[win], comp_u, mask=lane_live)
     if st_ref is not None:
         comp_st = _take_tile(new_st, sel).reshape(block_c)
-        pl.store(st_ref, idx, comp_st, mask=lane_live)
+        pltpu.store(st_ref.at[win], comp_st, mask=lane_live)
 
 
 def fused_extend_pruned_mp_pallas(col_idx: jnp.ndarray, offsets: jnp.ndarray,

@@ -18,6 +18,14 @@ All four kernel families (``extend_fused``, ``intersect``, ``segsum``,
 ``flash_attention``) resolve their ``interpret`` default through
 :func:`resolve_interpret`, so CI and real hardware flip one switch
 instead of auditing every callsite.
+
+:data:`TPU_REFUSALS` records the mining kernels the TPU compiler
+(Mosaic, jax/jaxlib 0.9.0 with libtpu 0.0.34) refuses to lower, with its
+message; :func:`require_lowerable` turns a compiled-mode request for one
+of them on a TPU into an error raised before anything is traced, so no
+caller silently drops to interpret mode or to the XLA path.
+``tests/test_chip_compile.py`` keeps the table honest by compiling each
+kernel for a described v5e chip.
 """
 from __future__ import annotations
 
@@ -62,3 +70,38 @@ def resolve_interpret(interpret: bool | None = None) -> bool:
     if interpret is not None:
         return bool(interpret)
     return jax.default_backend() != "tpu"
+
+
+# Kernel -> the TPU compiler's refusal.  The 1-D VMEM gathers
+# (``jnp.take`` on a flat ref) are the common cause; the two-pass
+# kernel's first pass already stops at its (1,)-element count block.
+TPU_REFUSALS = {
+    "fused_extend": "NotImplementedError: Only 2D gather is supported",
+    "fused_extend_pruned":
+        "NotImplementedError: Only 2D gather is supported",
+    "fused_extend_pruned_mp":
+        "ValueError: The Pallas TPU lowering currently requires that rank "
+        "1 block shapes ... be a multiple of the tiling size (pass 1, "
+        "i32[1] count block); pass 2: NotImplementedError: Only 2D gather "
+        "is supported",
+    "fused_extend_edge": "NotImplementedError: Only 2D gather is supported",
+    "intersect_count": "NotImplementedError: Only 2D gather is supported",
+}
+
+
+class KernelNotLowerable(RuntimeError):
+    """A compiled Pallas kernel was requested that the TPU cannot lower."""
+
+
+def require_lowerable(kernels, what: str, interpret: bool) -> None:
+    """Refuse ``what`` up front when it would launch a compiled kernel
+    that the TPU compiler refuses (see :data:`TPU_REFUSALS`)."""
+    if interpret or jax.default_backend() != "tpu":
+        return
+    refused = [f"{k}: {TPU_REFUSALS[k]}" for k in kernels
+               if k in TPU_REFUSALS]
+    if refused:
+        raise KernelNotLowerable(
+            f"{what} needs Pallas kernels the TPU compiler refuses in "
+            f"compiled mode ({'; '.join(refused)}); use "
+            "backend='reference' (pure XLA) on the TPU")

@@ -146,7 +146,6 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str,
 def run_mining(multi_pod: bool, out_dir: str) -> dict:
     """Dry-run the distributed mining step on the production mesh."""
     from jax.sharding import PartitionSpec as PSpec
-    from jax.experimental.shard_map import shard_map
     from repro.launch.mesh import make_production_mesh
     from repro.core import make_mc_app, bounded_mine_vertex
     from repro.core.api import GraphCtx
@@ -167,11 +166,8 @@ def run_mining(multi_pod: bool, out_dir: str) -> dict:
             (edges_per_dev * 32, edges_per_dev * 32))
     axes = tuple(mesh.axis_names)
 
-    def local(rp, ci, src, dst, n_blk):
-        ctx2 = GraphCtx(row_ptr=rp, col_idx=ci, labels=None,
-                        n_vertices=n_vertices, n_edges=n_edges,
-                        max_degree=max_deg, n_steps=12)
-        cnt, p_map, ovf = bounded_mine_vertex(ctx2, app, src, dst,
+    def local(c, src, dst, n_blk):
+        cnt, p_map, ovf = bounded_mine_vertex(c, app, src, dst,
                                               n_blk[0], caps)
         for ax in axes:
             cnt = jax.lax.psum(cnt, ax)
@@ -179,17 +175,15 @@ def run_mining(multi_pod: bool, out_dir: str) -> dict:
         return cnt, p_map
 
     espec = PSpec(axes)
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(PSpec(), PSpec(), espec, espec, espec),
-                   out_specs=(PSpec(), PSpec()), check_rep=False)
-    args = (ctx.row_ptr, ctx.col_idx,
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(PSpec(), espec, espec, espec),
+                       out_specs=(PSpec(), PSpec()), check_vma=False)
+    args = (ctx,
             jax.ShapeDtypeStruct((n_chips * edges_per_dev,), jnp.int32),
             jax.ShapeDtypeStruct((n_chips * edges_per_dev,), jnp.int32),
             jax.ShapeDtypeStruct((n_chips,), jnp.int32))
     t0 = time.time()
-    with mesh:
-        lowered = jax.jit(fn).lower(*args)
-        compiled = lowered.compile()
+    compiled = jax.jit(fn).lower(*args).compile()
     cost = compiled.cost_analysis()
     coll = parse_collective_bytes(compiled.as_text())
     mem = compiled.memory_analysis()

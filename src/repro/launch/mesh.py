@@ -9,13 +9,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """Version-tolerant ``jax.make_mesh``: pass Auto axis types when the
-    installed jax has them (>= 0.5), plain mesh otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types on every axis."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

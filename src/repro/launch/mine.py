@@ -36,6 +36,7 @@ from repro.core import (Miner, Pattern, graph_stats, make_cf_app,
                         pattern_set_app, pattern_set_names,
                         triangle_count_fused)
 from repro.graph import generators as G
+from repro.launch.compile_cache import configure_compile_cache
 from repro.obs import metrics, report, trace
 
 
@@ -163,6 +164,7 @@ def main(argv=None):
                          "OUT.json writes the JSON snapshot, any other "
                          "path the text form")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     if args.trace:
         trace.enable(sync=args.trace_sync)

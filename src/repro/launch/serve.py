@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.registry import get_arch
+from repro.launch.compile_cache import configure_compile_cache
 from repro.obs import metrics, report, trace
 
 
@@ -67,6 +68,18 @@ def serve_recsys(arch, smoke: bool, batch: int, seed: int):
     return scores
 
 
+def query_app(query: str, minsup: int, stats):
+    """The mining app a served query names: a built-in app (tc, k-cf,
+    k-mc, k-fsm), else a library pattern compiled with a matching order
+    picked by the resident graph's statistics."""
+    from repro.core import Pattern, pattern_app
+    from repro.launch.mine import make_app
+    try:
+        return make_app(query, minsup)
+    except SystemExit:
+        return pattern_app(Pattern.named(query), stats=stats)
+
+
 def serve_mine(args):
     """Answer mining queries on a resident graph; returns per-query dicts.
 
@@ -76,8 +89,8 @@ def serve_mine(args):
     the whole query stream, and each response carries the executor's
     plan provenance (``plan_reports()``).
     """
-    from repro.core import Miner, Pattern, graph_stats, pattern_app
-    from repro.launch.mine import load_graph, make_app
+    from repro.core import Miner, graph_stats
+    from repro.launch.mine import load_graph
 
     g = load_graph(args.graph, labels=args.labels)
     stats = graph_stats(g)
@@ -87,12 +100,7 @@ def serve_mine(args):
     first_h = metrics.histogram("serve.first_ms")
     warm_h = metrics.histogram("serve.warm_ms")
     for query in [q.strip() for q in args.queries.split(",") if q.strip()]:
-        try:
-            app = make_app(query, args.minsup)
-        except SystemExit:
-            # not a built-in app name: compile it as a pattern query,
-            # matching order picked by the resident graph's statistics
-            app = pattern_app(Pattern.named(query), stats=stats)
+        app = query_app(query, args.minsup, stats)
         miner = Miner(g, app)
         with trace.span("serve.query", cat="serve", query=query):
             t0 = time.time()
@@ -125,7 +133,7 @@ def serve_mine(args):
     return results
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None,
                     help="model arch to serve (required unless --mine)")
@@ -165,7 +173,12 @@ def main(argv=None):
                     help="dump the metrics registry after serving "
                          "('-'/no arg = text to stdout, *.json = JSON "
                          "snapshot) — the /metrics endpoint shape")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    configure_compile_cache()
     if args.trace:
         trace.enable(sync=args.trace_sync)
     if args.mine:

@@ -85,3 +85,26 @@ def test_miner_reuse_no_retrace(er_graph, er_nx):
     ref = triangle_count(er_nx)
     assert m.run().count == ref
     assert m.run().count == ref
+
+
+def test_graph_ctx_is_a_pytree_passed_to_compiled_runs(er_graph):
+    """GraphCtx round-trips through jax.tree_util (arrays are leaves, the
+    ints static), and the compiled executor — which takes it as an
+    argument — counts exactly what the host run counted."""
+    import jax
+
+    from repro.core.api import GraphCtx
+
+    m = Miner(er_graph, make_mc_app(3))
+    leaves, treedef = jax.tree_util.tree_flatten(m.ctx)
+    assert all(isinstance(x, jax.Array) for x in leaves)
+    back = jax.tree_util.tree_unflatten(treedef, leaves)
+    assert isinstance(back, GraphCtx)
+    assert (back.n_vertices, back.max_degree, back.n_steps) == \
+        (m.ctx.n_vertices, m.ctx.max_degree, m.ctx.n_steps)
+    host = m.run()                           # host inspection pass
+    m.ctx = back                             # same graph, rebuilt pytree
+    warm = m.run()                           # jitted executor, ctx an arg
+    assert m.plan_reports()[0]["executions"] == 1
+    assert warm.count == host.count
+    np.testing.assert_array_equal(warm.p_map, host.p_map)

@@ -88,3 +88,27 @@ def _run_subprocess(code, devices=4, timeout=600):
                          timeout=timeout)
     assert out.returncode == 0, out.stderr[-4000:]
     assert "OK" in out.stdout
+
+
+def test_compile_cache_placed_from_outside_or_in_checkout(monkeypatch,
+                                                          tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set; without it
+    the cache goes to the fixed, git-ignored <checkout>/.jax_cache."""
+    import jax
+
+    from repro.launch import compile_cache as cc
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(cc.ENV_VAR, str(tmp_path))
+        assert cc.configure_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv(cc.ENV_VAR)
+        path = cc.configure_compile_cache()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

@@ -10,7 +10,7 @@ from _hyp import given, settings, strategies as st
 from oracles import motif_counts, triangle_count
 from repro.core import (Miner, available_backends, bounded_mine_vertex,
                         get_backend, make_cf_app, make_fsm_app, make_mc_app,
-                        make_tc_app)
+                        make_mc_set_app, make_tc_app)
 from repro.core.phases import PhaseBackend, register_backend
 from repro.core.phases.pallas import PallasExtendBackend
 from repro.core.phases.pallas_mp import PallasMPBackend
@@ -200,6 +200,77 @@ def test_extend_pruned_bitwise_parity(aname, make_app, seed, kbackend):
     assert (st_r is None) == (st_p is None)
     if st_r is not None:
         np.testing.assert_array_equal(st_r, st_p)
+
+
+def _diamond_labeled_app():
+    from repro.core.apps.psm import pattern_app
+    from repro.core.patterns import Pattern
+    return pattern_app(Pattern.from_edges(
+        [(0, 1), (1, 2), (2, 0), (2, 3)], labels=[0, 1, 0, 1]))
+
+
+def _dag_app_without_add_hooks():
+    import dataclasses
+    return dataclasses.replace(make_cf_app(3), to_add=None, to_add_bits=None)
+
+
+CHUNKED_APPS = PRUNED_APPS + [
+    ("4-mc-set", lambda: make_mc_set_app(4)),
+    ("diamond-labeled", _diamond_labeled_app),
+    ("3-cf-no-add-hooks", _dag_app_without_add_hooks)]
+
+
+@pytest.mark.parametrize("aname,make_app", CHUNKED_APPS)
+@pytest.mark.parametrize("pack_bytes", [0, 4 << 20], ids=["csr", "bitmap"])
+@pytest.mark.parametrize("caps", [(1024, 512), (40, 512), (1024, 7)],
+                         ids=["roomy", "cand-overflow", "out-overflow"])
+@pytest.mark.parametrize("chunk", [16, 1 << 16],
+                         ids=["many-chunks", "one-chunk"])
+def test_chunked_extend_matches_one_shot(aname, make_app, pack_bytes, caps,
+                                         chunk, monkeypatch):
+    """The reference backend's chunked extend (per-parent values forward-
+    filled over each chunk of slots) returns the bit-identical level,
+    embeddings and counts of the one-shot enumerate-filter-compact, on
+    every level of the run, with chunks much smaller than the level or
+    one chunk for all of it, and under candidate or survivor overflow."""
+    import jax.numpy as jnp
+    from repro.core.api import resolve_state_kernel
+    from repro.core.embedding_list import init_level0_vertex, materialize
+    from repro.core.phases import reference as R
+
+    monkeypatch.setattr(R, "CHUNK_SLOTS", chunk)
+    cand_cap, out_cap = caps
+    g = G.erdos_renyi(24, 0.3, seed=3, labels=2)
+    app = make_app()
+    m = Miner(g, app, pack_max_bytes=pack_bytes)
+    assert (m.ctx.packed is None) == (pack_bytes == 0)
+    src, dst = m.init_edges()
+    n = jnp.int32(src.shape[0])
+    emb = materialize(init_level0_vertex(src, dst, int(n)))
+    state = (app.init_state(m.ctx, emb, n) if app.init_state is not None
+             else None)
+    for _ in range(app.max_size - 2):
+        k = emb.shape[1]
+        row, u, slot, add, total = R._vertex_candidates(
+            m.ctx, app, emb, n, state, cand_cap)
+        upd = resolve_state_kernel(app, k)
+        new_st = (None if upd is None else
+                  R.apply_state_kernel(m.ctx, upd, emb, row, u, slot, state))
+        want, want_emb = R.finish_extend_vertex(emb, row, u, add, out_cap,
+                                                new_state=new_st)
+        got, got_emb, got_total = R.extend_vertex_chunked(
+            m.ctx, app, emb, n, state, cand_cap, out_cap)
+        assert int(got_total) == int(total)
+        assert int(got.n) == int(want.n)
+        np.testing.assert_array_equal(got.vid, want.vid)
+        np.testing.assert_array_equal(got.idx, want.idx)
+        np.testing.assert_array_equal(got_emb, want_emb)
+        assert (got.state is None) == (want.state is None)
+        if want.state is not None:
+            np.testing.assert_array_equal(got.state, want.state)
+        if int(total) > cand_cap or int(want.n) > out_cap:
+            break
+        emb, state, n = want_emb, want.state, want.n
 
 
 def test_pruned_kernel_matches_oracle():

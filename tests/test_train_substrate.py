@@ -128,7 +128,6 @@ def test_grad_compression_psum():
     4 host devices)."""
     code = """
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.train.compression import psum_grads
 from repro.launch.mesh import make_mesh
@@ -137,8 +136,9 @@ x = jnp.linspace(-1, 1, 4 * 32).reshape(4, 32)
 def f(xs, comp):
     return psum_grads(xs[0], "data", comp)
 for comp in (None, "int8"):
-    g = shard_map(lambda xs: f(xs, comp), mesh=mesh, in_specs=(P("data"),),
-                  out_specs=P(), check_rep=False)(x)
+    g = jax.shard_map(lambda xs: f(xs, comp), mesh=mesh,
+                      in_specs=(P("data"),), out_specs=P(),
+                      check_vma=False)(x)
     ref = np.asarray(x).sum(0)
     err = np.abs(np.asarray(g) - ref).max()
     assert err < (1e-6 if comp is None else 0.05), (comp, err)
