@@ -29,7 +29,6 @@ from repro.analysis.core import Project, SourceFile
 # Names whose truthiness marks a host-only (or obs-enabled) region.
 HOST_GUARD_NAMES = {"host", "collect_stats", "checkpoint_cb"}
 HOST_GUARD_ATTRS = {"traceable", "on"}
-HOST_GUARD_CALLS = {"sync_enabled"}
 
 
 @dataclasses.dataclass
@@ -248,12 +247,6 @@ def is_host_guard(test: ast.expr) -> bool:
         if isinstance(node, ast.Attribute) and node.attr in \
                 HOST_GUARD_ATTRS:
             return True
-        if isinstance(node, ast.Call):
-            fn = node.func
-            name = fn.id if isinstance(fn, ast.Name) else (
-                fn.attr if isinstance(fn, ast.Attribute) else None)
-            if name in HOST_GUARD_CALLS:
-                return True
     return False
 
 

@@ -235,8 +235,6 @@ class BlockQueue:
                 _M.observe("blocks.mine_ms", dt * 1e3)
         finally:
             total = stage_s + mine_s
-            _M.inc("blocks.stage_s", stage_s)
-            _M.inc("blocks.mine_s", mine_s)
             if total > 0:
                 _M.set_gauge("blocks.stage_overlap", mine_s / total)
 

@@ -102,23 +102,19 @@ class MineResult:
 def _obs_op(name: str, backend_name: str, fn):
     """Wrap a host-dispatched phase op in an (optional) trace span.
 
-    Dispatch granularity by default: the span measures the host-side
-    dispatch of the jitted op (JAX async dispatch returns before the
-    device finishes), which is exactly what the warm path is allowed to
-    pay — no forced sync.  With ``--trace-sync``
-    (:func:`repro.obs.trace.sync_enabled`) the wrapper blocks on the
-    op's result for exact attribution.  Only applied to the host
-    driver's jitted closures (``_PhaseOps(jit=True)``) — the raw ops
-    traced into the executor's single jit must stay uninstrumented.
+    The span measures the host-side dispatch of the jitted op (JAX async
+    dispatch returns before the device finishes), which is exactly what
+    the warm path is allowed to pay — no forced sync; the op's device
+    time is in a profile (``--profile``), under its span.  Only applied
+    to the host path's jitted closures (``_PhaseOps(jit=True)``) — the
+    raw ops traced into the executor's single jit must stay
+    uninstrumented.
     """
     def wrapped(*args, **kwargs):
         if not _T.on:
             return fn(*args, **kwargs)
         with _T.span("op." + name, cat="phase", backend=backend_name):
-            out = fn(*args, **kwargs)
-            if _T.sync_enabled():
-                jax.block_until_ready(out)
-        return out
+            return fn(*args, **kwargs)
     return wrapped
 
 
@@ -262,8 +258,9 @@ class _VertexPipeline:
         app = self.ops.app
         if app.get_pattern is not None or (app.needs_reduce
                                            and level == app.max_size - 1):
-            pm, pat, self.state = self.ops._reduce(self.emb, self.n,
-                                                   self.state)
+            with jax.named_scope("reduce"):
+                pm, pat, self.state = self.ops._reduce(self.emb, self.n,
+                                                       self.state)
             self.p_map = pm
         elif app.update_state_kernel is None:
             # apps without a kernel state update get a fresh memo slot per
@@ -313,7 +310,8 @@ class _EdgePipeline:
         return range(2, self.ops.app.max_size)
 
     def pre_loop(self, policy):
-        self._reduce_filter(policy)
+        with jax.named_scope("level1"):
+            self._reduce_filter(policy)
         return 1                  # the initial reduce+filter is "level 1"
 
     def _frontier(self):
@@ -349,10 +347,13 @@ class _EdgePipeline:
 
     def _reduce_filter(self, policy):
         app = self.ops.app
-        codes, supports, pat, _ = self.ops.reduce_e(self.levels,
-                                                    self.axis_names)
+        with jax.named_scope("reduce"):
+            codes, supports, pat, _ = self.ops.reduce_e(self.levels,
+                                                        self.axis_names)
         self.codes, self.supports = codes, supports
-        if app.needs_filter:
+        if not app.needs_filter:
+            return
+        with jax.named_scope("filter"):
             sup_of = supports[jnp.clip(pat, 0, app.max_patterns - 1)]
             keep = sup_of >= app.min_support
             n_keep = jnp.sum(
@@ -361,7 +362,7 @@ class _EdgePipeline:
             out_cap = policy.filter_cap(n_keep)
             self.levels = self.ops._filter_e(self.levels, keep,
                                              out_cap=out_cap)
-            self._front = None
+        self._front = None
 
     def checkpoint_payload(self):
         return None if self.supports is None else np.asarray(self.supports)
@@ -405,10 +406,14 @@ def run_level_loop(pipe, policy, collect_stats: bool = False,
                                 time.perf_counter() - t0,
                                 nbytes + pipe.frontier_nbytes()))
 
-    # Observability is host-path only: a traceable policy means this
-    # loop body is being traced into a jit (executor / shard_map /
-    # estimator probe), where a span would time tracing, not running,
-    # and any int() would force a device sync the warm path must not pay.
+    # Host spans and counters are host-path only: a traceable policy
+    # means this loop body is being traced into a jit (executor /
+    # shard_map / estimator probe), where a span would time tracing, not
+    # running, and any int() would force a device sync the warm path
+    # must not pay.  What names the traced path's device time is the
+    # ``level{L}`` scope (op metadata, read back by
+    # ``Miner.op_scopes()``); its counts come back through the policy
+    # (``PlanCapPolicy.level_counts``).
     host = not policy.traceable
     t0 = time.perf_counter()
     pre_level = pipe.pre_loop(policy)
@@ -418,14 +423,16 @@ def run_level_loop(pipe, policy, collect_stats: bool = False,
         t0 = time.perf_counter()
         sp = (_T.span("level", level=level).__enter__()
               if (host and _T.on) else None)
-        cand_cap, out_cap = policy.extend_caps(pipe)
-        # one fused enumeration per level: extend_pruned applies the
-        # app's eager toAdd predicate and stream-compacts in the same
-        # pass, returning the true counts — the policy's overflow check
-        # (plan replay) consumes them instead of a second inspection run
-        n_cand, n_surv = pipe.extend(cand_cap, out_cap)
-        policy.note_extend(n_cand, n_surv, cand_cap, out_cap)
-        pipe.reduce_filter(level, policy)
+        with jax.named_scope(f"level{level}"):
+            cand_cap, out_cap = policy.extend_caps(pipe)
+            # one fused enumeration per level: extend_pruned applies the
+            # app's eager toAdd predicate and stream-compacts in the same
+            # pass, returning the true counts — the policy's overflow
+            # check (plan replay) consumes them instead of a second
+            # inspection run
+            n_cand, n_surv = pipe.extend(cand_cap, out_cap)
+            policy.note_extend(n_cand, n_surv, cand_cap, out_cap)
+            pipe.reduce_filter(level, policy)
         if host:
             # cap-utilization: true counts over the planned caps — the
             # exact-planner contract (util <= 1) made visible, and the
@@ -592,6 +599,16 @@ class Miner:
                             "capabilities": dict(caps_report)})
         return out
 
+    def op_scopes(self) -> dict[str, dict[str, str]]:
+        """Per compiled executor, ``{module: {HLO instruction: scope
+        path}}``: which ``level{L}/<phase>`` scope each op of the
+        executor programs came from (``level2/probe/gather``), to name
+        the ops of a device profile, which carry instruction names."""
+        out: dict[str, dict[str, str]] = {}
+        for ex in self._executors.values():
+            out.update(ex.op_scopes())
+        return out
+
     def pack_hit_rate(self) -> Optional[float]:
         """Degree-weighted probability a connectivity probe hits the
         packed adjacency bitmap (None when no pack was built)."""
@@ -666,26 +683,39 @@ class Miner:
                    or checkpoint_cb is not None
                    else (plan_source, safety_factor, sample_size,
                          plan_seed, cache))
+        t0, wait0 = time.perf_counter(), self._wait_s()
         with _T.span("miner.run", app=self.app.name,
                      backend=self.backend.name, kind=self.app.kind,
                      plan_source=plan_source):
-            if self.app.kind == "edge":
-                # paper §5.2: blocking disabled for FSM (global support
-                # sync); bounded/sharded FSM paths: bounded_mine_edge.
-                return self._run_edge(collect_stats, checkpoint_cb, cache,
-                                      seeding)
-            src, dst = self.init_edges()
-            m = int(src.shape[0])
-            if block_bytes and not block_size:
-                block_size = self._auto_block_size(m, block_bytes,
-                                                   sample_size,
-                                                   safety_factor, plan_seed)
-            if not block_size or block_size >= m:
-                return self._run_vertex_full(src, dst, m, collect_stats,
-                                             checkpoint_cb, cache, seeding)
-            return self._run_vertex_blocked(src, dst, m, block_size,
-                                            collect_stats, checkpoint_cb,
-                                            cache, seeding, resume_from)
+            try:
+                if self.app.kind == "edge":
+                    # paper §5.2: blocking disabled for FSM (global
+                    # support sync); bounded/sharded FSM paths:
+                    # bounded_mine_edge.
+                    return self._run_edge(collect_stats, checkpoint_cb,
+                                          cache, seeding)
+                with _T.span("miner.worklist"):
+                    src, dst = self.init_edges()
+                    m = int(src.shape[0])
+                if block_bytes and not block_size:
+                    block_size = self._auto_block_size(
+                        m, block_bytes, sample_size, safety_factor,
+                        plan_seed)
+                if not block_size or block_size >= m:
+                    return self._run_vertex_full(src, dst, m, collect_stats,
+                                                 checkpoint_cb, cache,
+                                                 seeding)
+                return self._run_vertex_blocked(src, dst, m, block_size,
+                                                collect_stats, checkpoint_cb,
+                                                cache, seeding, resume_from)
+            finally:
+                # the run's host seconds: all but the executor's wait
+                # for the device
+                _M.set_gauge("miner.host_s", time.perf_counter() - t0
+                             - (self._wait_s() - wait0))
+
+    def _wait_s(self) -> float:
+        return sum(ex.wait_s for ex in self._executors.values())
 
     def _auto_block_size(self, m: int, budget_bytes: int,
                          sample_size: int = 256,
@@ -753,8 +783,9 @@ class Miner:
             return self._host_run(_VertexPipeline(self.ops, src, dst, m),
                                   ex, collect_stats, checkpoint_cb)
         pad = cap0 - m
-        cnt, p_map = ex.execute(jnp.pad(src, (0, pad)),
-                                jnp.pad(dst, (0, pad)), m)
+        with _T.span("miner.worklist", pad=pad):
+            src, dst = jnp.pad(src, (0, pad)), jnp.pad(dst, (0, pad))
+        cnt, p_map = ex.execute(src, dst, m)
         return MineResult(count=cnt,
                           p_map=p_map if self._p_map_meaningful() else None)
 
@@ -818,10 +849,11 @@ class Miner:
             return self._host_run(_EdgePipeline(self.ops), ex,
                                   collect_stats, checkpoint_cb)
         pad = cap0 - m
-        codes, supports = ex.execute_edge(
-            jnp.pad(self.ctx.usrc, (0, pad)),
-            jnp.pad(self.ctx.udst, (0, pad)),
-            jnp.pad(jnp.arange(m, dtype=jnp.int32), (0, pad)), m)
+        with _T.span("miner.worklist", pad=pad):
+            worklist = (jnp.pad(self.ctx.usrc, (0, pad)),
+                        jnp.pad(self.ctx.udst, (0, pad)),
+                        jnp.pad(jnp.arange(m, dtype=jnp.int32), (0, pad)))
+        codes, supports = ex.execute_edge(*worklist, m)
         mask = (supports >= self.app.min_support) & (codes != _INT_MAX)
         return MineResult(count=int(mask.sum()), codes=codes,
                           supports=supports)

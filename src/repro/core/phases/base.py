@@ -36,26 +36,12 @@ import jax.numpy as jnp
 
 from repro.core.api import GraphCtx, MiningApp
 from repro.core.embedding_list import EmbeddingLevel
-from repro.obs import metrics as _M
 
 
 class PhaseBackend:
     """Abstract extend/reduce/filter op set.  Subclass and register."""
 
     name: str = "abstract"
-
-    def note_op(self, op: str, **labels) -> None:
-        """Count one *tracing* of a backend op into a jit program.
-
-        Backend ops run at jit-trace time, so this counts compilations
-        (how many distinct programs embed this op), not executions —
-        executions are the executor's ``executor.replays`` counter.
-        Called from op overrides; keyed by backend name so the metrics
-        dump shows which backend's kernels a run actually compiled.
-        """
-        if _M.on:
-            _M.inc("phase.op_tracings", op=op, backend=self.name,
-                   **labels)
 
     # -- capability metadata ----------------------------------------------
     # How the backend's extend_pruned resolves cross-tile survivor offsets,

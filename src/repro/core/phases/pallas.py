@@ -175,8 +175,6 @@ class PallasExtendBackend(ReferenceBackend):
             return super().extend_pruned(ctx, app, emb, n_valid, state,
                                          cand_cap, out_cap,
                                          fuse_filter=fuse_filter)
-        self.note_op("extend_pruned", mode="fused",
-                     compaction=self.compaction)
         cap, k = emb.shape
         offsets, starts, vlo, vhi = self._kernel_inputs(ctx, app, emb,
                                                         n_valid, state)

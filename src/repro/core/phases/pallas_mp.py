@@ -46,12 +46,3 @@ class PallasMPBackend(PallasExtendBackend):
 
     _pruned_kernel = staticmethod(fused_extend_pruned_mp)
     kernels = ("fused_extend", "fused_extend_pruned_mp", "fused_extend_edge")
-
-    def extend_pruned(self, ctx, app, emb, n_valid, state, cand_cap,
-                      out_cap, fuse_filter=True):
-        # note_op in the parent records mode/compaction under self.name
-        # ("pallas-mp"), so the metrics dump distinguishes two-pass-scan
-        # tracings from the sequential backend's.
-        return super().extend_pruned(ctx, app, emb, n_valid, state,
-                                     cand_cap, out_cap,
-                                     fuse_filter=fuse_filter)

@@ -198,26 +198,32 @@ def _vertex_candidates(ctx: GraphCtx, app: MiningApp, emb: jnp.ndarray,
     """
     emb, state = _pad_empty_frontier(emb, state)
     cap, k = emb.shape
-    deg = vertex_ext_degrees(ctx, app, emb, n_valid, state)
-    slot_parent, rank, total = expand_ragged(deg.reshape(-1), cand_cap)
-    row = slot_parent // k
-    col = slot_parent % k
-    live = slot_parent >= 0
-    row_c = jnp.clip(row, 0, cap - 1)
-    v = emb.reshape(-1)[row_c * k + jnp.clip(col, 0, k - 1)]
-    ptr = ctx.row_ptr[jnp.clip(v, 0, ctx.n_vertices - 1)] + rank
-    # zero-edge graphs: col_idx is empty and a gather from it is invalid
-    col_idx = ctx.col_idx if ctx.n_edges else jnp.zeros(1, ctx.col_idx.dtype)
-    u = col_idx[jnp.clip(ptr, 0, max(ctx.n_edges - 1, 0))]
-    u = jnp.where(live, u, -1)
-    src_slot = jnp.clip(col, 0, k - 1).astype(jnp.int32)
+    with jax.named_scope("rows"):
+        deg = vertex_ext_degrees(ctx, app, emb, n_valid, state)
+    with jax.named_scope("fill"):
+        slot_parent, rank, total = expand_ragged(deg.reshape(-1), cand_cap)
+    with jax.named_scope("draw"):
+        row = slot_parent // k
+        col = slot_parent % k
+        live = slot_parent >= 0
+        row_c = jnp.clip(row, 0, cap - 1)
+        v = emb.reshape(-1)[row_c * k + jnp.clip(col, 0, k - 1)]
+        ptr = ctx.row_ptr[jnp.clip(v, 0, ctx.n_vertices - 1)] + rank
+        # zero-edge graphs: col_idx is empty and a gather from it is
+        # invalid
+        col_idx = (ctx.col_idx if ctx.n_edges
+                   else jnp.zeros(1, ctx.col_idx.dtype))
+        u = col_idx[jnp.clip(ptr, 0, max(ctx.n_edges - 1, 0))]
+        u = jnp.where(live, u, -1)
+        src_slot = jnp.clip(col, 0, k - 1).astype(jnp.int32)
     pred = resolve_kernel_predicate(app, k)
-    if pred is not None:
-        add = apply_kernel_predicate(ctx, pred, emb, row_c, u, src_slot,
-                                     state, live)
-    else:
-        add = vertex_add_mask(ctx, app, emb, row_c, u, src_slot, state,
-                              live)
+    with jax.named_scope("probe"):
+        if pred is not None:
+            add = apply_kernel_predicate(ctx, pred, emb, row_c, u, src_slot,
+                                         state, live)
+        else:
+            add = vertex_add_mask(ctx, app, emb, row_c, u, src_slot, state,
+                                  live)
     return row_c, u, src_slot, add, total
 
 
@@ -254,16 +260,18 @@ def finish_extend_vertex(emb: jnp.ndarray, row: jnp.ndarray, u: jnp.ndarray,
         cand_vid = jnp.stack([row, u], axis=1)
         cand_vid = jax.lax.optimization_barrier(cand_vid)
         row, u = cand_vid[:, 0], cand_vid[:, 1]
-    gather, n_new = compact_mask(add, out_cap)
-    live = jnp.arange(out_cap) < n_new
-    vid = jnp.where(live, u[gather], -1)
-    idx = jnp.where(live, row[gather], 0)
-    st = (None if new_state is None
-          else jnp.where(live, new_state[gather], 0).astype(jnp.int32))
-    level = EmbeddingLevel(vid=vid.astype(jnp.int32),
-                           idx=idx.astype(jnp.int32), n=n_new, state=st)
-    new_emb = jnp.concatenate(
-        [emb[idx], vid[:, None].astype(jnp.int32)], axis=1)
+    with jax.named_scope("compact"):
+        gather, n_new = compact_mask(add, out_cap)
+        live = jnp.arange(out_cap) < n_new
+        vid = jnp.where(live, u[gather], -1)
+        idx = jnp.where(live, row[gather], 0)
+        st = (None if new_state is None
+              else jnp.where(live, new_state[gather], 0).astype(jnp.int32))
+    with jax.named_scope("emit"):
+        level = EmbeddingLevel(vid=vid.astype(jnp.int32),
+                               idx=idx.astype(jnp.int32), n=n_new, state=st)
+        new_emb = jnp.concatenate(
+            [emb[idx], vid[:, None].astype(jnp.int32)], axis=1)
     return level, new_emb
 
 
@@ -329,43 +337,44 @@ def extend_vertex_chunked(ctx: GraphCtx, app: MiningApp, emb: jnp.ndarray,
     emb, state = _pad_empty_frontier(emb, state)
     cap, k = emb.shape
     chunk = min(cand_cap, CHUNK_SLOTS)
-    ext = vertex_ext_mask(ctx, app, emb, n_valid, state)
-    raw = [emb[:, j] for j in range(k)]
-    vtx = [jnp.clip(e, 0, ctx.n_vertices - 1) for e in raw]
-    lo = [ctx.row_ptr[v] for v in vtx]
-    hi = [ctx.row_ptr[v + 1] for v in vtx]
-    cum, acc = [], jnp.zeros((cap,), jnp.int32)
-    for j in range(k):                # slots of the row's columns <= j
-        acc = acc + jnp.where(ext[:, j], hi[j] - lo[j], 0)
-        cum.append(acc)
-    row_offsets = jnp.cumsum(acc)
-    total = row_offsets[-1].astype(jnp.int32)
-    limit = jnp.minimum(total, jnp.int32(cand_cap))
-
-    # per-row table over the rows with candidates, in slot order
-    rid, n_rows = compact_mask(acc > 0, cap)
     pred = resolve_kernel_predicate(app, k)
     upd = resolve_state_kernel(app, k)
     csr_conn = ctx.packed is None and ctx.search == "binary"
     with_labels = pred is not None and getattr(pred, "needs_labels", False)
-    columns = [row_offsets - acc, jnp.arange(cap, dtype=jnp.int32)]
-    columns += cum[:-1] + lo + raw
-    if csr_conn:
-        columns += hi
-    if state is not None:
-        columns.append(state)
     labels = (ctx.labels if ctx.labels is not None
               else jnp.zeros((1,), jnp.int32))
-    if with_labels:
-        columns += [labels[jnp.clip(e, 0, labels.shape[0] - 1)]
-                    for e in raw]
-    live_r = jnp.arange(cap, dtype=jnp.int32) < n_rows
-    table = [c.astype(jnp.int32)[rid] for c in columns]
-    table[0] = jnp.where(live_r, table[0], _INT_MAX)
-    table = jnp.stack(table)
-    # a window of chunk + 1 rows covers any chunk: pad past the end
-    pad = jnp.zeros((table.shape[0], chunk + 1), jnp.int32)
-    table = jnp.concatenate([table, pad.at[0].set(_INT_MAX)], axis=1)
+    with jax.named_scope("rows"):
+        ext = vertex_ext_mask(ctx, app, emb, n_valid, state)
+        raw = [emb[:, j] for j in range(k)]
+        vtx = [jnp.clip(e, 0, ctx.n_vertices - 1) for e in raw]
+        lo = [ctx.row_ptr[v] for v in vtx]
+        hi = [ctx.row_ptr[v + 1] for v in vtx]
+        cum, acc = [], jnp.zeros((cap,), jnp.int32)
+        for j in range(k):            # slots of the row's columns <= j
+            acc = acc + jnp.where(ext[:, j], hi[j] - lo[j], 0)
+            cum.append(acc)
+        row_offsets = jnp.cumsum(acc)
+        total = row_offsets[-1].astype(jnp.int32)
+        limit = jnp.minimum(total, jnp.int32(cand_cap))
+
+        # per-row table over the rows with candidates, in slot order
+        rid, n_rows = compact_mask(acc > 0, cap)
+        columns = [row_offsets - acc, jnp.arange(cap, dtype=jnp.int32)]
+        columns += cum[:-1] + lo + raw
+        if csr_conn:
+            columns += hi
+        if state is not None:
+            columns.append(state)
+        if with_labels:
+            columns += [labels[jnp.clip(e, 0, labels.shape[0] - 1)]
+                        for e in raw]
+        live_r = jnp.arange(cap, dtype=jnp.int32) < n_rows
+        table = [c.astype(jnp.int32)[rid] for c in columns]
+        table[0] = jnp.where(live_r, table[0], _INT_MAX)
+        table = jnp.stack(table)
+        # a window of chunk + 1 rows covers any chunk: pad past the end
+        pad = jnp.zeros((table.shape[0], chunk + 1), jnp.int32)
+        table = jnp.concatenate([table, pad.at[0].set(_INT_MAX)], axis=1)
 
     col_idx = ctx.col_idx if ctx.n_edges else jnp.zeros(1, ctx.col_idx.dtype)
     last_edge = max(ctx.n_edges - 1, 0)
@@ -374,51 +383,59 @@ def extend_vertex_chunked(ctx: GraphCtx, app: MiningApp, emb: jnp.ndarray,
     def step(carry):
         c, n_surv, out_vid, out_idx, out_st = carry
         base = c * chunk
-        q0 = jnp.searchsorted(table[0], base, side="right") - 1
-        win = jax.lax.dynamic_slice_in_dim(table, q0, chunk + 1, axis=1)
-        f = _forward_fill(jnp.maximum(win[0] - base, 0), list(win), chunk)
-        slot = base + lane
-        live = slot < limit
-        off = slot - f[0]                       # slot within its row
-        row_c = jnp.clip(f[1], 0, cap - 1)
-        cum_f, f = f[2:1 + k], f[1 + k:]
-        lo_f, emb_cols, f = f[:k], tuple(f[k:2 * k]), f[2 * k:]
-        col = sum((off >= b).astype(jnp.int32) for b in cum_f)
-        col = jnp.asarray(col, jnp.int32)
-        first = _pick(col, [jnp.zeros_like(off)] + cum_f)
-        u = col_idx[jnp.clip(_pick(col, lo_f) + off - first, 0, last_edge)]
-        u = jnp.where(live, u, -1)
-        if csr_conn:
-            hi_f, f = f[:k], f[k:]
-            conn = tuple(binary_contains(col_idx, lo_f[j], hi_f[j], u,
-                                         ctx.n_steps)
-                         & (emb_cols[j] >= 0) & (u >= 0)
-                         for j in range(k))
-        else:
-            conn = tuple(ctx.is_connected(e, u) for e in emb_cols)
-        st = None
-        if state is not None:
-            st, f = f[0], f[1:]
-        st0 = jnp.zeros(u.shape, jnp.int32) if st is None else st
-        if pred is None:
-            add = _add_mask(ctx, app, jnp.stack(emb_cols, axis=1), u, col,
-                            st, live)
-        elif with_labels:
-            lab_u = labels[jnp.clip(u, 0, labels.shape[0] - 1)]
-            add = pred(emb_cols, u, col, st0, conn, tuple(f[:k]),
-                       lab_u) & live
-        else:
-            add = pred(emb_cols, u, col, st0, conn) & live
+        with jax.named_scope("fill"):
+            q0 = jnp.searchsorted(table[0], base, side="right") - 1
+            win = jax.lax.dynamic_slice_in_dim(table, q0, chunk + 1, axis=1)
+            f = _forward_fill(jnp.maximum(win[0] - base, 0), list(win),
+                              chunk)
+        with jax.named_scope("draw"):
+            slot = base + lane
+            live = slot < limit
+            off = slot - f[0]                   # slot within its row
+            row_c = jnp.clip(f[1], 0, cap - 1)
+            cum_f, f = f[2:1 + k], f[1 + k:]
+            lo_f, emb_cols, f = f[:k], tuple(f[k:2 * k]), f[2 * k:]
+            col = sum((off >= b).astype(jnp.int32) for b in cum_f)
+            col = jnp.asarray(col, jnp.int32)
+            first = _pick(col, [jnp.zeros_like(off)] + cum_f)
+            u = col_idx[jnp.clip(_pick(col, lo_f) + off - first, 0,
+                                 last_edge)]
+            u = jnp.where(live, u, -1)
+        with jax.named_scope("probe"):
+            if csr_conn:
+                hi_f, f = f[:k], f[k:]
+                conn = tuple(binary_contains(col_idx, lo_f[j], hi_f[j], u,
+                                             ctx.n_steps)
+                             & (emb_cols[j] >= 0) & (u >= 0)
+                             for j in range(k))
+            else:
+                conn = tuple(ctx.is_connected(e, u) for e in emb_cols)
+            st = None
+            if state is not None:
+                st, f = f[0], f[1:]
+            st0 = jnp.zeros(u.shape, jnp.int32) if st is None else st
+            if pred is None:
+                add = _add_mask(ctx, app, jnp.stack(emb_cols, axis=1), u,
+                                col, st, live)
+            elif with_labels:
+                lab_u = labels[jnp.clip(u, 0, labels.shape[0] - 1)]
+                add = pred(emb_cols, u, col, st0, conn, tuple(f[:k]),
+                           lab_u) & live
+            else:
+                add = pred(emb_cols, u, col, st0, conn) & live
+            new_st = (None if upd is None else
+                      upd(emb_cols, u, col, st0, conn).astype(jnp.int32))
         # survivors land at their running offset; every other lane adds
         # 0 at the next survivor's position (sorted, collision-safe)
-        keep = add.astype(jnp.int32)
-        at = n_surv + jnp.cumsum(keep) - keep
-        out_vid = scatter_sorted(out_vid, at, (u + 1) * keep)
-        out_idx = scatter_sorted(out_idx, at, row_c * keep)
-        if upd is not None:
-            new_st = upd(emb_cols, u, col, st0, conn).astype(jnp.int32)
-            out_st = scatter_sorted(out_st, at, new_st * keep)
-        return c + 1, n_surv + jnp.sum(keep), out_vid, out_idx, out_st
+        with jax.named_scope("compact"):
+            keep = add.astype(jnp.int32)
+            at = n_surv + jnp.cumsum(keep) - keep
+            out_vid = scatter_sorted(out_vid, at, (u + 1) * keep)
+            out_idx = scatter_sorted(out_idx, at, row_c * keep)
+            if new_st is not None:
+                out_st = scatter_sorted(out_st, at, new_st * keep)
+            n_surv = n_surv + jnp.sum(keep)
+        return c + 1, n_surv, out_vid, out_idx, out_st
 
     zeros = jnp.zeros((out_cap,), jnp.int32)
     carry = (jnp.int32(0), jnp.int32(0), zeros, zeros, zeros)
@@ -428,10 +445,11 @@ def extend_vertex_chunked(ctx: GraphCtx, app: MiningApp, emb: jnp.ndarray,
         carry = jax.lax.while_loop(lambda c: c[0] * chunk < limit, step,
                                    carry)
     _, n_new, vid, idx, st = carry
-    vid = vid - 1                             # vid is stored plus one
-    level = EmbeddingLevel(vid=vid, idx=idx, n=n_new,
-                           state=None if upd is None else st)
-    new_emb = jnp.concatenate([emb[idx], vid[:, None]], axis=1)
+    with jax.named_scope("emit"):
+        vid = vid - 1                         # vid is stored plus one
+        level = EmbeddingLevel(vid=vid, idx=idx, n=n_new,
+                               state=None if upd is None else st)
+        new_emb = jnp.concatenate([emb[idx], vid[:, None]], axis=1)
     return level, new_emb, total
 
 
@@ -464,37 +482,42 @@ def _edge_candidates(ctx: GraphCtx, app: MiningApp,
                      v0, vid, his, eid, n_valid: jnp.ndarray,
                      cand_cap: int):
     cap, E = vid.shape
-    slots, fresh = edge_vertex_slots(v0, vid, his)
     n_slots = E + 1
-    valid = jnp.arange(cap, dtype=jnp.int32) < n_valid
-    ext = fresh & valid[:, None]
-    if app.to_extend is not None:
-        ext = ext & app.to_extend(ctx, slots)
-    deg = jnp.where(ext, ctx.degree(slots), 0)        # [cap, E+1]
-    slot_parent, rank, total = expand_ragged(deg.reshape(-1), cand_cap)
-    row = jnp.clip(slot_parent // n_slots, 0, cap - 1)
-    s = jnp.clip(slot_parent % n_slots, 0, n_slots - 1)
-    live = slot_parent >= 0
-    w = slots[row, s]                                  # source vertex
-    ptr = ctx.row_ptr[jnp.clip(w, 0, ctx.n_vertices - 1)] + rank
-    ptr = jnp.clip(ptr, 0, ctx.n_edges - 1)
-    u = jnp.where(live, ctx.col_idx[ptr], -1)          # destination vertex
-    new_eid = jnp.where(live, ctx.edge_uid[ptr], -1)
+    with jax.named_scope("rows"):
+        slots, fresh = edge_vertex_slots(v0, vid, his)
+        valid = jnp.arange(cap, dtype=jnp.int32) < n_valid
+        ext = fresh & valid[:, None]
+        if app.to_extend is not None:
+            ext = ext & app.to_extend(ctx, slots)
+        deg = jnp.where(ext, ctx.degree(slots), 0)    # [cap, E+1]
+    with jax.named_scope("fill"):
+        slot_parent, rank, total = expand_ragged(deg.reshape(-1), cand_cap)
+    with jax.named_scope("draw"):
+        row = jnp.clip(slot_parent // n_slots, 0, cap - 1)
+        s = jnp.clip(slot_parent % n_slots, 0, n_slots - 1)
+        live = slot_parent >= 0
+        w = slots[row, s]                              # source vertex
+        ptr = ctx.row_ptr[jnp.clip(w, 0, ctx.n_vertices - 1)] + rank
+        ptr = jnp.clip(ptr, 0, ctx.n_edges - 1)
+        u = jnp.where(live, ctx.col_idx[ptr], -1)      # destination vertex
+        new_eid = jnp.where(live, ctx.edge_uid[ptr], -1)
 
-    # endpoints of existing edges (for the shares-endpoint test)
-    eids_row = eid[row]                                # [cand, E]
-    e_uid = jnp.clip(eids_row, 0, max(ctx.n_uedges - 1, 0))
-    e_src = ctx.usrc[e_uid]
-    e_dst = ctx.udst[e_uid]
-    add = is_auto_canonical_edge(ctx, eids_row, new_eid, w, u, e_src, e_dst)
-    if app.to_add_vertex_mask is not None:
-        # per-candidate-vertex eager mask (e.g. FSM's label-frequency
-        # prune) — the form the fused edge kernel applies in-VMEM
-        vm = app.to_add_vertex_mask(ctx)
-        add = add & vm[jnp.clip(u, 0, ctx.n_vertices - 1)]
-    elif app.to_add is not None:
-        add = add & app.to_add(ctx, slots[row], u, None)
-    add = add & live
+    with jax.named_scope("probe"):
+        # endpoints of existing edges (for the shares-endpoint test)
+        eids_row = eid[row]                            # [cand, E]
+        e_uid = jnp.clip(eids_row, 0, max(ctx.n_uedges - 1, 0))
+        e_src = ctx.usrc[e_uid]
+        e_dst = ctx.udst[e_uid]
+        add = is_auto_canonical_edge(ctx, eids_row, new_eid, w, u, e_src,
+                                     e_dst)
+        if app.to_add_vertex_mask is not None:
+            # per-candidate-vertex eager mask (e.g. FSM's label-frequency
+            # prune) — the form the fused edge kernel applies in-VMEM
+            vm = app.to_add_vertex_mask(ctx)
+            add = add & vm[jnp.clip(u, 0, ctx.n_vertices - 1)]
+        elif app.to_add is not None:
+            add = add & app.to_add(ctx, slots[row], u, None)
+        add = add & live
     return row, s, u, new_eid, add, total
 
 
@@ -514,15 +537,16 @@ def candidate_bound_edge(ctx, app, v0, vid, his, n_valid):
 
 def finish_extend_edge(row, s, u, new_eid, add, out_cap: int):
     """Compact surviving edge candidates into the next SoA level."""
-    gather, n_new = compact_mask(add, out_cap)
-    live_out = jnp.arange(out_cap) < n_new
-    return EmbeddingLevel(
-        vid=jnp.where(live_out, u[gather], -1).astype(jnp.int32),
-        idx=jnp.where(live_out, row[gather], 0).astype(jnp.int32),
-        n=n_new,
-        his=jnp.where(live_out, s[gather], 0).astype(jnp.int32),
-        eid=jnp.where(live_out, new_eid[gather], -1).astype(jnp.int32),
-    )
+    with jax.named_scope("compact"):
+        gather, n_new = compact_mask(add, out_cap)
+        live_out = jnp.arange(out_cap) < n_new
+        return EmbeddingLevel(
+            vid=jnp.where(live_out, u[gather], -1).astype(jnp.int32),
+            idx=jnp.where(live_out, row[gather], 0).astype(jnp.int32),
+            n=n_new,
+            his=jnp.where(live_out, s[gather], 0).astype(jnp.int32),
+            eid=jnp.where(live_out, new_eid[gather], -1).astype(jnp.int32),
+        )
 
 
 def extend_edge(ctx, app, v0, vid, his, eid, n_valid, cand_cap, out_cap):
@@ -914,7 +938,6 @@ class ReferenceBackend(PhaseBackend):
 
     def extend_pruned(self, ctx, app, emb, n_valid, state, cand_cap,
                       out_cap, fuse_filter=True):
-        self.note_op("extend_pruned", mode="xla")
         if fuse_filter and self._enumerates_in_xla():
             return extend_vertex_chunked(ctx, app, emb, n_valid, state,
                                          cand_cap, out_cap)
@@ -947,7 +970,6 @@ class ReferenceBackend(PhaseBackend):
 
     def extend_edge(self, ctx, app, v0, vid, his, eid, n_valid, cand_cap,
                     out_cap):
-        self.note_op("extend_edge", mode="xla")
         row, s, u, new_eid, add, total = self._edge_candidates(
             ctx, app, v0, vid, his, eid, n_valid, cand_cap)
         return finish_extend_edge(row, s, u, new_eid, add, out_cap), total

@@ -37,10 +37,10 @@ shared level loop under a :class:`PlanCapPolicy`.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import hashlib
 import json
 import os
+import re
 import tempfile
 import time
 from typing import Optional
@@ -491,6 +491,7 @@ class PlanCapPolicy:
         self._li = 0
         self._fi = 0
         self._ovf = jnp.zeros((), bool)
+        self.counts: list = []        # traced (n_cand, n_surv) per level
 
     def extend_caps(self, pipe):
         cand_cap, out_cap = self.plan.caps[self._li]
@@ -499,6 +500,7 @@ class PlanCapPolicy:
 
     def note_extend(self, n_cand, n_surv, cand_cap: int,
                     out_cap: int) -> None:
+        self.counts.append((n_cand, n_surv))
         self._ovf = (self._ovf | (n_cand > cand_cap)
                      | (n_surv > out_cap))
 
@@ -510,6 +512,13 @@ class PlanCapPolicy:
 
     def overflow(self):
         return self._ovf
+
+    def level_counts(self):
+        """int32[levels, 2]: each level's true (candidates, survivors)."""
+        if not self.counts:
+            return jnp.zeros((0, 2), jnp.int32)
+        return jnp.stack([jnp.stack([c, s]) for c, s in self.counts]
+                         ).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -540,19 +549,16 @@ SAMPLE_OUT_CAP = 4096
 
 
 class _ProbePolicy(PlanCapPolicy):
-    """Replay fixed probe capacities; collect the true traced counts."""
+    """Replay fixed probe capacities; collect the true traced counts
+    (``counts`` per level, ``n_keep`` per support filter)."""
 
     def __init__(self, plan: MiningPlan):
         super().__init__(plan)
-        self.n_cand: list = []
-        self.n_surv: list = []
         self.n_keep: list = []
 
-    def note_extend(self, n_cand, n_surv, cand_cap: int,
-                    out_cap: int) -> None:
-        self.n_cand.append(n_cand)
-        self.n_surv.append(n_surv)
-        super().note_extend(n_cand, n_surv, cand_cap, out_cap)
+    def outputs(self) -> tuple:
+        return (tuple(c for c, _ in self.counts),
+                tuple(s for _, s in self.counts), tuple(self.n_keep))
 
     def filter_cap(self, n_keep) -> int:
         self.n_keep.append(n_keep)
@@ -592,9 +598,12 @@ def estimate_plan(miner, cap0: int, sample_size: int = 256,
     overflow-grow-and-retry loop guarantees correct results even when
     every level is under-estimated.
     """
+    t0 = time.perf_counter()
     with _T.span("plan.estimate", cat="plan", sample_size=sample_size):
-        return _estimate_plan(miner, cap0, sample_size, safety_factor,
-                              seed)
+        plan = _estimate_plan(miner, cap0, sample_size, safety_factor, seed)
+    _M.inc("plan.estimate_s", time.perf_counter() - t0,
+           kind=miner.app.kind)
+    return plan
 
 
 def _estimate_plan(miner, cap0, sample_size, safety_factor, seed
@@ -651,8 +660,7 @@ def _estimate_plan(miner, cap0, sample_size, safety_factor, seed
             pipe = E._EdgePipeline(ops_for(c), src=s, dst=d, eid=e, n=n)
             policy = _ProbePolicy(probe_plan)
             E.run_level_loop(pipe, policy)
-            return (tuple(policy.n_cand), tuple(policy.n_surv),
-                    tuple(policy.n_keep))
+            return policy.outputs()
         args = (ctx.usrc[jnp.asarray(idx)], ctx.udst[jnp.asarray(idx)],
                 jnp.asarray(idx, jnp.int32), jnp.int32(n_sample))
     else:
@@ -660,8 +668,7 @@ def _estimate_plan(miner, cap0, sample_size, safety_factor, seed
             pipe = E._VertexPipeline(ops_for(c), s, d, n)
             policy = _ProbePolicy(probe_plan)
             E.run_level_loop(pipe, policy)
-            return (tuple(policy.n_cand), tuple(policy.n_surv),
-                    tuple(policy.n_keep))
+            return policy.outputs()
         args = (jnp.asarray(np.asarray(src)[idx]),
                 jnp.asarray(np.asarray(dst)[idx]), jnp.int32(n_sample))
     n_cand, n_surv, n_keep = jax.jit(probe)(ctx, *args)
@@ -713,9 +720,13 @@ def plan_program(plan: MiningPlan, app, backend, fuse_filter: bool = True,
     """The jitted whole-run program that replays ``plan``.
 
     Vertex plans: ``(ctx, src, dst, n_valid) -> (count, p_map,
-    overflowed)``; edge plans: ``(ctx, src, dst, eid, n_valid) -> (codes,
-    supports, overflowed)``.  The graph context is the first argument —
-    an input buffer of the executable, never a constant folded into it.
+    overflowed, level_counts)``; edge plans: ``(ctx, src, dst, eid,
+    n_valid) -> (codes, supports, overflowed, level_counts)``, where
+    ``level_counts`` is int32[levels, 2], each level's true (candidates,
+    survivors).  The graph context is the first argument — an input
+    buffer of the executable, never a constant folded into it.  The
+    program is named ``mine_<kind>_<cap0>`` (XLA module
+    ``jit_mine_<kind>_<cap0>``).
     """
     from repro.core import engine as E
 
@@ -728,26 +739,64 @@ def plan_program(plan: MiningPlan, app, backend, fuse_filter: bool = True,
             pipe = E._VertexPipeline(ops_for(ctx), src, dst, n_valid)
             policy = PlanCapPolicy(plan)
             E.run_level_loop(pipe, policy)
-            return pipe.bounded_result(policy)
+            return (*pipe.bounded_result(policy), policy.level_counts())
     else:
         def fn(ctx, src, dst, eid, n_valid):
             pipe = E._EdgePipeline(ops_for(ctx), src=src, dst=dst, eid=eid,
                                    n=n_valid)
             policy = PlanCapPolicy(plan)
             E.run_level_loop(pipe, policy)
-            return pipe.bounded_result(policy)
+            return (*pipe.bounded_result(policy), policy.level_counts())
+    fn.__name__ = f"mine_{plan.kind}_{plan.cap0}"
     return jax.jit(fn)
+
+
+_HLO_MODULE = re.compile(r"^HloModule\s+([^\s,]+)")
+_HLO_OP = re.compile(r'^\s*(?:ROOT\s+)?%?([^\s=]+)\s*=.*\bop_name="'
+                     r'jit\([^)]*\)/([^"]*)"')
+PHASES = ("rows", "fill", "draw", "probe", "compact", "emit", "reduce",
+          "filter")
+_LEVEL_PHASE = re.compile(r"(?:^|/)(level\d+)/(?:.*/)?(" + "|".join(PHASES)
+                          + r")(?:/|$)")
+
+
+def hlo_op_scopes(text: str) -> dict[str, dict[str, str]]:
+    """``{module: {instruction: scope path}}`` from an HLO module's text.
+
+    The path is the instruction's ``op_name`` metadata after the
+    program's own ``jit(<fn>)/`` (``level2/probe/gather``).  A fusion
+    carries its root's ``op_name``, so a fusion that crosses scopes is
+    named by its root's.  Instructions XLA made without that prefix (a
+    ``cumsum``'s ``reduce_window_sum``) are left out.
+    """
+    lines = text.splitlines()
+    head = _HLO_MODULE.match(lines[0]) if lines else None
+    module = head.group(1) if head else ""
+    ops = {}
+    for line in lines:
+        m = _HLO_OP.match(line)
+        if m:
+            ops[m.group(1)] = m.group(2)
+    return {module: ops}
+
+
+def level_phase(path: str) -> Optional[str]:
+    """``"level2/probe"`` for a scope path inside a level's phase (the
+    innermost such pair), ``None`` for any other path."""
+    found = _LEVEL_PHASE.findall(path)
+    return "/".join(found[-1]) if found else None
 
 
 class MiningExecutor:
     """One compiled mining run, reused across blocks / runs / queries.
 
-    Holds the plan for one (graph, app, backend, cap0) signature and a
-    jit cache keyed by the plan's capacities: every edge block of a run —
-    and every repeated run — goes through the same XLA executable with a
-    single device sync, no per-level host inspection.  ``execute`` /
-    ``execute_edge`` retry with a grown plan when the overflow flag comes
-    back set; that re-plan loop is the only host-side control flow left.
+    Holds the plan for one (graph, app, backend, cap0) signature and the
+    executables compiled for it, keyed by the plan's capacities: every
+    edge block of a run — and every repeated run — goes through the same
+    XLA executable with a single device sync, no per-level host
+    inspection.  ``execute`` / ``execute_edge`` retry with a grown plan
+    when the overflow flag comes back set; that re-plan loop is the only
+    host-side control flow left.
     """
 
     def __init__(self, miner, cap0: int, plan: Optional[MiningPlan] = None,
@@ -773,6 +822,8 @@ class MiningExecutor:
         self.n_compiles = 0
         self.n_executions = 0
         self.n_replans = 0
+        self.wait_s = 0.0             # host seconds blocked on the device
+        self.last_level_counts = None  # device int32[levels, 2]
 
     # -- plan management ----------------------------------------------------
 
@@ -841,49 +892,95 @@ class MiningExecutor:
 
     # -- compilation --------------------------------------------------------
 
-    def _fn(self):
+    def _executable(self, args):
+        """The plan's executable; compiled ahead of time on first use
+        (trace, lower, XLA compile or persistent-cache load), which is
+        all ``executor.compile`` times."""
         key = (self._plan.caps, self._plan.filter_caps)
-        fn = self._fns.get(key)
-        if fn is None:
-            fn = self._build(self._plan)
-            self._fns[key] = fn
+        exe = self._fns.get(key)
+        if exe is None:
+            t0 = time.perf_counter()
+            with _T.span("executor.compile", cat="executor", kind=self.kind):
+                exe = self._build(self._plan, args)
+            _M.inc("executor.compile_s", time.perf_counter() - t0,
+                   kind=self.kind)
+            _M.inc("executor.compiles", kind=self.kind)
+            self._fns[key] = exe
             self.n_compiles += 1
-        return fn
+        return exe
 
-    def _build(self, plan: MiningPlan):
+    def _build(self, plan: MiningPlan, args):
         miner = self.miner
         program = plan_program(plan, miner.app, miner.backend,
                                fuse_filter=miner.fuse_filter,
                                materialize_fn=miner._materialize)
-        return functools.partial(program, miner.ctx)
+        return program.lower(miner.ctx, *args).compile()
+
+    def op_scopes(self) -> dict[str, dict[str, str]]:
+        """``{module: {instruction: scope path}}`` of the executable
+        this executor holds (:func:`hlo_op_scopes`); its module is named
+        after the executor's ``cap0``, so the executors of a Miner never
+        share one."""
+        out: dict[str, dict[str, str]] = {}
+        for exe in self._fns.values():
+            out.update(hlo_op_scopes(exe.as_text()))
+        return out
 
     # -- execution ----------------------------------------------------------
 
+    def _note_level_counts(self, counts, overflowed: bool) -> dict:
+        """Read a replay's per-level counts back (tracing on only) and
+        record them under the host path's names; returns span args."""
+        counts = np.asarray(counts).tolist()
+        args = {}
+        for li, ((cand_cap, out_cap), (nc, ns)) in enumerate(
+                zip(self._plan.caps, counts)):
+            level = li + 2
+            args[f"candidates.level{level}"] = nc
+            args[f"survivors.level{level}"] = ns
+            if overflowed:
+                continue        # the retry's counts are the run's
+            _M.inc("mine.candidates", nc, level=level)
+            _M.inc("mine.survivors", ns, level=level)
+            _M.set_gauge("mine.cap_utilization",
+                         ns / out_cap if out_cap else 0.0, level=level)
+            _M.set_gauge("mine.cand_cap_utilization",
+                         nc / cand_cap if cand_cap else 0.0, level=level)
+        if not overflowed:
+            _M.observe("executor.replay_candidates",
+                       sum(nc for nc, _ in counts))
+        return args
+
     def _run_with_retry(self, *args):
-        """Call the compiled plan; on overflow grow it and recompile.
+        """Run the plan's executable; on overflow grow it and recompile.
 
         Timing here is exact without extra syncs: ``bool(ovf)``
-        data-depends on the whole pipeline, so each iteration's wall
-        time covers the full device execution.  A call whose
-        ``(caps, filter_caps)`` key is not in the jit cache yet pays
-        tracing + XLA compilation; that first call is recorded as
-        ``executor.compile_s``, later ones as ``executor.replay_s``.
+        (``executor.wait``) data-depends on the whole pipeline, so each
+        replay's wall time covers the full device execution.  Compiling
+        is timed apart (``executor.compile``); every execution is a
+        replay.  The per-level counts stay on the device
+        (``last_level_counts``) unless tracing is on.
         """
         for attempt in range(self.max_retries + 1):
-            fresh = (self._plan.caps,
-                     self._plan.filter_caps) not in self._fns
-            what = "executor.compile" if fresh else "executor.replay"
+            exe = self._executable(args)
             t0 = time.perf_counter()
-            with _T.span(what, cat="executor", kind=self.kind,
+            with _T.span("executor.replay", cat="executor", kind=self.kind,
                          attempt=attempt) as sp:
-                *out, ovf = self._fn()(*args)
+                *out, ovf, counts = exe(self.miner.ctx, *args)
                 self.n_executions += 1
-                overflowed = bool(ovf)    # forces the device sync
+                with _T.span("executor.wait", cat="executor"):
+                    tw = time.perf_counter()
+                    overflowed = bool(ovf)    # forces the device sync
+                    wait = time.perf_counter() - tw
+                self.wait_s += wait
+                self.last_level_counts = counts
                 sp.set(overflow=overflowed)
-            dt = time.perf_counter() - t0
-            _M.inc(what + "_s", dt, kind=self.kind)
-            _M.inc("executor.compiles" if fresh else "executor.replays",
+                if _T.on:
+                    sp.set(**self._note_level_counts(counts, overflowed))
+            _M.inc("executor.replay_s", time.perf_counter() - t0,
                    kind=self.kind)
+            _M.inc("executor.wait_s", wait, kind=self.kind)
+            _M.inc("executor.replays", kind=self.kind)
             if not overflowed:
                 return out
             if attempt == self.max_retries:
@@ -897,7 +994,8 @@ class MiningExecutor:
         """Vertex-induced block: one compiled call -> (count, p_map)."""
         assert self.kind == "vertex"
         cnt, p_map = self._run_with_retry(src, dst, jnp.int32(n_valid))
-        return int(cnt), np.asarray(p_map)
+        with _T.span("executor.fetch", cat="executor"):
+            return int(cnt), np.asarray(p_map)
 
     def execute_edge(self, src, dst, eid, n_valid
                      ) -> tuple[np.ndarray, np.ndarray]:
@@ -905,4 +1003,5 @@ class MiningExecutor:
         assert self.kind == "edge"
         codes, supports = self._run_with_retry(src, dst, eid,
                                                jnp.int32(n_valid))
-        return np.asarray(codes), np.asarray(supports)
+        with _T.span("executor.fetch", cat="executor"):
+            return np.asarray(codes), np.asarray(supports)

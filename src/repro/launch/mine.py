@@ -26,8 +26,10 @@ every pattern in a single fused traversal.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
+import jax
 import numpy as np
 
 from repro.core import (Miner, Pattern, graph_stats, make_cf_app,
@@ -36,6 +38,7 @@ from repro.core import (Miner, Pattern, graph_stats, make_cf_app,
                         pattern_set_app, pattern_set_names,
                         triangle_count_fused)
 from repro.graph import generators as G
+from repro.launch import profile
 from repro.launch.compile_cache import configure_compile_cache
 from repro.obs import metrics, report, trace
 
@@ -153,10 +156,13 @@ def main(argv=None):
                     help="record host spans + plan-provenance events and "
                          "write Chrome trace-event JSON (open in "
                          "https://ui.perfetto.dev)")
-    ap.add_argument("--trace-sync", action="store_true",
-                    help="with --trace: block on dispatched device work "
-                         "inside each instrumented span so device phases "
-                         "are attributed exactly (serializes dispatch)")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="run a JAX profiler session around the mining, "
+                         "written under DIR (TensorBoard / xprof), with "
+                         "the program's spans on the device trace's "
+                         "clock; DIR/op_scopes.json names the executor "
+                         "ops by level and phase, and the device time by "
+                         "scope is printed")
     ap.add_argument("--metrics", nargs="?", const="-", default=None,
                     metavar="OUT",
                     help="dump the metrics registry after the run: no "
@@ -166,8 +172,9 @@ def main(argv=None):
     args = ap.parse_args(argv)
     configure_compile_cache()
 
-    if args.trace:
-        trace.enable(sync=args.trace_sync)
+    if args.trace or args.profile:
+        trace.enable(annotate=(jax.profiler.TraceAnnotation
+                               if args.profile else None))
 
     if args.pattern == "list":
         print("[mine] pattern library:", ", ".join(pattern_names()))
@@ -234,16 +241,25 @@ def main(argv=None):
         from repro.core import PlanCache
         plan_cache = PlanCache(plan_cache, max_entries=args.plan_cache_max)
     r = None
-    for i in range(max(args.repeat, 1)):
-        t0 = time.time()
-        r = miner.run(block_size=block_size, block_bytes=block_bytes,
-                      collect_stats=args.stats,
-                      plan_cache=plan_cache, plan_source=args.plan,
-                      safety_factor=args.safety_factor,
-                      sample_size=args.sample_size)
-        dt = time.time() - t0
-        if args.repeat > 1:
-            print(f"[mine] run {i}: {dt:.3f}s")
+    with (jax.profiler.trace(args.profile) if args.profile
+          else contextlib.nullcontext()):
+        for i in range(max(args.repeat, 1)):
+            t0 = time.time()
+            r = miner.run(block_size=block_size, block_bytes=block_bytes,
+                          collect_stats=args.stats,
+                          plan_cache=plan_cache, plan_source=args.plan,
+                          safety_factor=args.safety_factor,
+                          sample_size=args.sample_size)
+            dt = time.time() - t0
+            if args.repeat > 1:
+                print(f"[mine] run {i}: {dt:.3f}s")
+    if args.profile:
+        profile.save_op_scopes(args.profile, miner.op_scopes())
+        by_scope = sorted(profile.read(args.profile).items(),
+                          key=lambda kv: -kv[1])
+        print(f"[mine] profile: {args.profile}"
+              + ("; device seconds by scope: " + ", ".join(
+                  f"{k} {v:.6f}" for k, v in by_scope) if by_scope else ""))
     for rep in miner.plan_reports():
         print(f"[mine] plan cap0={rep['cap0']} source={rep['source']} "
               f"caps={rep['caps']} out_cap_total={rep['out_cap_total']} "

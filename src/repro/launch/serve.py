@@ -15,6 +15,7 @@ graphs from the profile-nearest cached plan (plan transfer).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 import jax
@@ -165,9 +166,10 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--trace", default=None, metavar="OUT.json",
                     help="record host spans + plan events; write Chrome "
                          "trace-event JSON (open in ui.perfetto.dev)")
-    ap.add_argument("--trace-sync", action="store_true",
-                    help="with --trace: exact device attribution "
-                         "(serializes dispatch)")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="mining mode: run a JAX profiler session around "
+                         "the queries, written under DIR, with the "
+                         "program's spans on the device trace's clock")
     ap.add_argument("--metrics", nargs="?", const="-", default=None,
                     metavar="OUT",
                     help="dump the metrics registry after serving "
@@ -179,10 +181,13 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv=None):
     args = build_parser().parse_args(argv)
     configure_compile_cache()
-    if args.trace:
-        trace.enable(sync=args.trace_sync)
+    if args.trace or args.profile:
+        trace.enable(annotate=(jax.profiler.TraceAnnotation
+                               if args.profile else None))
     if args.mine:
-        serve_mine(args)
+        with (jax.profiler.trace(args.profile) if args.profile
+              else contextlib.nullcontext()):
+            serve_mine(args)
         if args.trace:
             print(f"[serve] trace: {trace.save(args.trace)}")
         if args.metrics is not None:

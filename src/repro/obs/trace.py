@@ -21,22 +21,27 @@ no-op context manager without allocating.
 a dispatched JAX computation measures *dispatch* time (JAX's async
 dispatch returns before the device finishes).  Phases whose results are
 synchronized anyway (host inspection ``int()`` syncs, the executor's
-overflow-flag read) are exact for free; for exact attribution of the
-rest, :func:`enable` with ``sync=True`` (``--trace-sync``) makes
-instrumented call sites block until their results are ready — callers
-check :func:`sync_enabled` and do the blocking themselves, so this
-module stays dependency-free (no jax import).
+overflow-flag read, ``executor.wait``) are exact for free.  Device time
+is attributed on the profiler's clock instead: :func:`enable` takes an
+``annotate`` callable (``jax.profiler.TraceAnnotation``, passed by the
+caller so this module stays dependency-free) and every span opens an
+annotation of its own name for as long as it is open, so a profiler
+session records the program's spans on the same clock as the device's
+ops (``--profile DIR`` on the launch CLIs).  While enabled, Python's
+garbage collections are spans too (``python.gc``, from
+``gc.callbacks``).
 
 This module is intentionally free of any repro.* (or third-party)
 imports so every layer of the stack can use it without cycles.
 """
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
 import time
-from typing import Optional
+from typing import Callable, Optional
 
 # Module-level fast-path flag: hot call sites guard on `trace.on` and
 # skip all span machinery when tracing is disabled.  enable()/disable()
@@ -76,7 +81,8 @@ _NULL = _NullSpan()
 class Span:
     """One live ``"X"`` event; use as a context manager (or begin/end)."""
 
-    __slots__ = ("_tr", "name", "cat", "args", "_ts", "_cpu0", "_done")
+    __slots__ = ("_tr", "name", "cat", "args", "_ts", "_cpu0", "_done",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
         self._tr = tracer
@@ -86,12 +92,16 @@ class Span:
         self._ts = 0
         self._cpu0 = 0
         self._done = False
+        self._ann = None
 
     def set(self, **args) -> None:
         """Attach args discovered while the span is open (e.g. counts)."""
         self.args.update(args)
 
     def __enter__(self) -> "Span":
+        if self._tr.annotate is not None:
+            self._ann = self._tr.annotate(self.name)
+            self._ann.__enter__()
         self._ts = time.perf_counter_ns()
         self._cpu0 = time.process_time_ns()
         return self
@@ -106,6 +116,8 @@ class Span:
         self._done = True
         dur_ns = time.perf_counter_ns() - self._ts
         cpu_ns = time.process_time_ns() - self._cpu0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         tr = self._tr
         args = {k: _jsonable(v) for k, v in self.args.items()}
         args["cpu_us"] = cpu_ns / 1e3
@@ -119,11 +131,12 @@ class Span:
 class Tracer:
     """Event sink for one tracing session (see :func:`enable`)."""
 
-    def __init__(self, sync: bool = False):
+    def __init__(self, annotate: Optional[Callable] = None):
         self.events: list[dict] = []
         self.t0 = time.perf_counter_ns()
         self.pid = os.getpid()
-        self.sync = bool(sync)
+        self.annotate = annotate
+        self._gc: Optional[Span] = None
 
     def span(self, name: str, cat: str, args: dict) -> Span:
         return Span(self, name, cat, args)
@@ -141,46 +154,55 @@ class Tracer:
         return {"traceEvents": list(self.events),
                 "displayTimeUnit": "ms",
                 "otherData": {"tool": "repro.obs.trace",
-                              "sync": self.sync}}
+                              "annotated": self.annotate is not None}}
 
     def save(self, path: str) -> str:
         with open(path, "w") as f:
             json.dump(self.to_chrome(), f)
         return path
 
+    def on_gc(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` hook: one ``python.gc`` span per collection."""
+        if phase == "start":
+            self._gc = self.span("python.gc", "python",
+                                 {"generation": info["generation"]})
+            self._gc.__enter__()
+        elif self._gc is not None:
+            self._gc.set(collected=info["collected"])
+            self._gc.end()
+            self._gc = None
+
 
 # ---------------------------------------------------------------------------
 # Module API (the process-global tracer)
 
 
-def enable(sync: bool = False) -> Tracer:
+def enable(annotate: Optional[Callable] = None) -> Tracer:
     """Start a fresh tracing session; returns the live :class:`Tracer`.
 
-    ``sync=True`` is the ``--trace-sync`` mode: instrumented call sites
-    that dispatch device work (see :func:`sync_enabled`) block until
-    their results are ready so device-side phases are attributed
-    exactly, at the cost of serializing dispatch.
+    ``annotate(name)`` returns a context manager that each span enters
+    when it opens and exits when it ends: with
+    ``jax.profiler.TraceAnnotation`` the program's spans appear in a
+    running profiler session, on the device trace's clock.
     """
     global _tracer, on
-    _tracer = Tracer(sync=sync)
+    disable()
+    _tracer = Tracer(annotate=annotate)
+    gc.callbacks.append(_tracer.on_gc)
     on = True
     return _tracer
 
 
 def disable() -> None:
     global _tracer, on
+    if _tracer is not None and _tracer.on_gc in gc.callbacks:
+        gc.callbacks.remove(_tracer.on_gc)
     _tracer = None
     on = False
 
 
 def active() -> bool:
     return _tracer is not None
-
-
-def sync_enabled() -> bool:
-    """True when the tracer wants exact (blocking) device attribution."""
-    t = _tracer
-    return t is not None and t.sync
 
 
 def get() -> Optional[Tracer]:

@@ -5,6 +5,8 @@ Contracts under test:
 * the span tracer nests, exports valid Chrome trace-event JSON
   (:mod:`repro.obs.validate` is the schema), and costs < 100ns per
   guarded call site when disabled (the ``if trace.on:`` fast path);
+  its ``annotate`` hook opens one annotation per span, in nesting
+  order, and garbage collections are spans only while it is enabled;
 * the histogram's log2 bucket math and percentile bounds;
 * the registry's typed get-or-create, render/snapshot shapes;
 * live-bytes drift detection (actual > predicted fires the warning);
@@ -12,7 +14,8 @@ Contracts under test:
   plus plan-provenance events and per-level cap-utilization gauges;
   ``serve --mine`` reports p50/p99 over the query stream; the block
   scheduler records stage/mine overlap; the executor distinguishes
-  compiles from replays.
+  compiles from replays; ``mine --profile`` puts the program's spans
+  into the profiler's trace.
 """
 import json
 import time
@@ -100,6 +103,55 @@ def test_disabled_guard_overhead_under_100ns():
                     pass
         best = min(best, (time.perf_counter_ns() - t0) / n)
     assert best < 100.0, f"disabled guard costs {best:.0f}ns/span"
+
+
+class _Recorder:
+    """An ``annotate`` hook that logs each annotation's enter and exit."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        rec = self
+
+        class _Ann:
+            def __enter__(self):
+                rec.log.append(("open", name))
+
+            def __exit__(self, *exc):
+                rec.log.append(("close", name))
+        return _Ann()
+
+
+def test_annotate_hook_opens_and_closes_once_per_span():
+    rec = _Recorder()
+    trace.enable(annotate=rec)
+    with trace.span("outer"):
+        sp = trace.span("level", level=2).__enter__()   # the loop's form
+        with trace.span("inner"):
+            pass
+        sp.end()
+        sp.end()                                        # idempotent
+    trace.disable()
+    assert [e for e in rec.log if e[1] != "python.gc"] == [
+        ("open", "outer"), ("open", "level"), ("open", "inner"),
+        ("close", "inner"), ("close", "level"), ("close", "outer")]
+
+
+def test_gc_spans_only_while_enabled():
+    import gc
+
+    gc.collect()
+    assert not trace.active()
+    trace.enable()
+    gc.collect()
+    tracer = trace.get()
+    trace.disable()
+    gc.collect()
+    spans = [e for e in tracer.events if e["name"] == "python.gc"]
+    assert any(e["args"]["generation"] == 2 for e in spans)
+    assert all(cb.__self__ is not tracer for cb in gc.callbacks
+               if hasattr(cb, "__self__"))
 
 
 # -- metrics ------------------------------------------------------------------
@@ -233,15 +285,28 @@ def test_mine_cli_trace_and_metrics_smoke(tmp_path, capsys):
     validate_metrics(snap)               # cap_utilization gauges in [0,1]
 
 
-def test_mine_cli_trace_sync(tmp_path):
+def test_mine_cli_profile_has_program_spans(tmp_path):
+    """``--profile DIR``: the program's spans are annotated into the
+    profiler session, on the host plane of the written xplane, and the
+    executor ops' scopes are written beside it."""
+    import glob
+
+    from jax.profiler import ProfileData
+
     from repro.launch.mine import main
 
-    tr = tmp_path / "t.json"
-    main(["--app", "tc", "--graph", "er:60,0.1",
-          "--trace", str(tr), "--trace-sync"])
-    doc = json.loads(tr.read_text())
-    assert doc["otherData"]["sync"] is True
-    validate_trace(doc)
+    main(["--app", "tc", "--graph", "er:60,0.1", "--plan", "estimate",
+          "--repeat", "2", "--profile", str(tmp_path)])
+    found = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert found
+    pd = ProfileData.from_file(found[0])
+    host = {e.name for p in pd.planes if p.name.startswith("/host:")
+            for line in p.lines for e in line.events}
+    assert {"miner.run", "executor.replay", "executor.wait",
+            "executor.fetch", "miner.worklist"} <= host
+    scopes = json.loads((tmp_path / "op_scopes.json").read_text())
+    assert any("level2/probe" in v for ops in scopes.values()
+               for v in ops.values())
 
 
 def test_blocked_mine_records_overlap_and_blocks(tmp_path):
@@ -268,11 +333,13 @@ def test_executor_compile_vs_replay_counters():
 
     m = Miner(G.erdos_renyi(60, 0.1, seed=1), make_tc_app())
     m.run()                              # plans (host inspection)
-    m.run()                              # first executor call: compile
-    m.run()                              # second: replay
+    m.run()                              # compile, then the first replay
+    m.run()                              # second replay
     assert metrics.value("executor.compiles", kind="vertex") == 1.0
-    assert metrics.value("executor.replays", kind="vertex") == 1.0
+    assert metrics.value("executor.replays", kind="vertex") == 2.0
     assert metrics.value("executor.compile_s", kind="vertex") > \
+        metrics.value("executor.replay_s", kind="vertex")
+    assert metrics.value("executor.wait_s", kind="vertex") <= \
         metrics.value("executor.replay_s", kind="vertex")
     assert metrics.value("plan.inspect", kind="vertex") == 1.0
 
