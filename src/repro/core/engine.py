@@ -238,7 +238,7 @@ class _VertexPipeline:
                                  cand_cap=cand_cap)
 
     def extend(self, cand_cap: int, out_cap: int):
-        new_level, self.emb, n_cand = self.ops._extend(
+        new_level, self.emb, n_cand, n_fill = self.ops._extend(
             self.emb, self.n, self.state, cand_cap=cand_cap,
             out_cap=out_cap)
         self.levels.append(new_level)
@@ -252,7 +252,7 @@ class _VertexPipeline:
             self.state = jnp.zeros(new_level.idx.shape, jnp.int32)
         else:
             self.state = self.state[new_level.idx]
-        return n_cand, new_level.n
+        return n_cand, new_level.n, n_fill
 
     def reduce_filter(self, level: int, policy):
         app = self.ops.app
@@ -340,7 +340,7 @@ class _EdgePipeline:
             cand_cap=cand_cap, out_cap=out_cap)
         self.levels.append(new_level)
         self._front = None
-        return n_cand, new_level.n
+        return n_cand, new_level.n, jnp.int32(0)
 
     def reduce_filter(self, level: int, policy):
         self._reduce_filter(policy)
@@ -430,23 +430,24 @@ def run_level_loop(pipe, policy, collect_stats: bool = False,
             # pass, returning the true counts — the policy's overflow
             # check (plan replay) consumes them instead of a second
             # inspection run
-            n_cand, n_surv = pipe.extend(cand_cap, out_cap)
-            policy.note_extend(n_cand, n_surv, cand_cap, out_cap)
+            n_cand, n_surv, n_fill = pipe.extend(cand_cap, out_cap)
+            policy.note_extend(n_cand, n_surv, n_fill, cand_cap, out_cap)
             pipe.reduce_filter(level, policy)
         if host:
             # cap-utilization: true counts over the planned caps — the
             # exact-planner contract (util <= 1) made visible, and the
             # figure every later perf PR reports buffer tightness with
-            nc, ns = int(n_cand), int(n_surv)
+            nc, ns, nf = int(n_cand), int(n_surv), int(n_fill)
             _M.set_gauge("mine.cap_utilization",
                          ns / out_cap if out_cap else 0.0, level=level)
             _M.set_gauge("mine.cand_cap_utilization",
                          nc / cand_cap if cand_cap else 0.0, level=level)
             _M.inc("mine.candidates", nc, level=level)
             _M.inc("mine.survivors", ns, level=level)
+            _M.inc("mine.fill_entries", nf, level=level)
             if sp is not None:
-                sp.set(candidates=nc, survivors=ns, cand_cap=cand_cap,
-                       out_cap=out_cap,
+                sp.set(candidates=nc, survivors=ns, fill_entries=nf,
+                       cand_cap=cand_cap, out_cap=out_cap,
                        utilization=ns / out_cap if out_cap else 0.0)
                 sp.end()
         if collect_stats:

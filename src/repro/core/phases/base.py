@@ -121,9 +121,11 @@ class PhaseBackend:
                       cand_cap: int, out_cap: int, fuse_filter: bool = True):
         """Fused extend + eager toAdd filter + stream compaction.
 
-        Returns ``(level, new_emb, n_candidates)``; the survivor count is
-        ``level.n``.  Because the true counts come back with the result,
-        a plan-replay caller needs **no** separate inspection pass — the
+        Returns ``(level, new_emb, n_candidates, n_fill)``; the survivor
+        count is ``level.n``, and ``n_fill`` the entries the reference
+        backend's forward fill scattered (0 where a backend fills none).
+        Because the true counts come back with the result, a plan-replay
+        caller needs **no** separate inspection pass — the
         overflow check reads them directly (``n_candidates > cand_cap`` or
         ``level.n > out_cap``).  Backends fuse as deeply as they can: the
         reference backend evaluates the resolved elementwise predicate and

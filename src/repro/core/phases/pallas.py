@@ -216,7 +216,7 @@ class PallasExtendBackend(ReferenceBackend):
                         0).astype(jnp.int32)
         level = EmbeddingLevel(vid=vid, idx=idx, n=n_surv, state=st_out)
         new_emb = jnp.concatenate([emb[idx], vid[:, None]], axis=1)
-        return level, new_emb, total
+        return level, new_emb, total, jnp.int32(0)
 
     def _edge_candidates(self, ctx: GraphCtx, app: MiningApp, v0, vid, his,
                          eid, n_valid: jnp.ndarray, cand_cap: int):

@@ -7,9 +7,11 @@ EXTEND is the inspection-execution candidate generation of paper §5.3:
      parent's output offset;
   2. *expansion*: each output slot finds its (parent, rank) — by a
      prefix sum over marks at the parents' first slots in the chunked
-     vertex extend (:func:`extend_vertex_chunked`), by binary search on
-     the offsets (``expand_ragged``) elsewhere — and gathers its
-     candidate vertex from CSR;
+     vertex extend (:func:`extend_vertex_chunked`, which scatters only
+     the rows a chunk spans, ``FILL_ROWS`` a pass, since the TPU charges
+     a scatter per entry, dropped ones too), by binary search on the
+     offsets (``expand_ragged``) elsewhere — and gathers its candidate
+     vertex from CSR;
   3. *write*: ``toAdd`` is evaluated on candidates *before* they are
      written (the paper's loop fusion / materialization avoidance, §5.2),
      and survivors are compacted into the next SoA level by a prefix-sum
@@ -289,22 +291,49 @@ def extend_vertex(ctx: GraphCtx, app: MiningApp, emb: jnp.ndarray,
 # level takes ceil(n_candidates / CHUNK_SLOTS) steps whatever its planned
 # capacity, so capacity padding costs no gathers.
 CHUNK_SLOTS = 1 << 16
+# Rows one pass of :func:`_forward_fill` scatters: a chunk takes as many
+# passes as the rows it spans need, one where they average 16 slots or
+# more.
+FILL_ROWS = CHUNK_SLOTS // 16
 
 
-def _forward_fill(pos: jnp.ndarray, vals: list, size: int) -> list:
-    """``out[r][i] = vals[r][j]`` for the last ``j`` with ``pos[j] <= i``.
+def _forward_fill(table: jnp.ndarray, q0: jnp.ndarray, q1: jnp.ndarray,
+                  base: jnp.ndarray, size: int, fill: int):
+    """``out[r][i] = table[r][j]`` for the last row ``j`` whose start
+    ``table[0][j] <= base + i``, for ``i < size``; and the entries it
+    scattered.
 
-    ``pos`` must start at 0 and not decrease; entries at or past ``size``
-    are dropped.  Each value is scattered as its difference to the
-    previous one and prefix-summed, which is exact in int32 (wraparound
-    cancels) and gathers nothing.
+    ``table[0]`` must increase; ``q0`` is the last row to start at or
+    before ``base``, ``q1`` the last before ``base + size``, and the
+    table must hold ``fill + 1`` rows past ``q1``.  Each value is
+    scattered as its difference to the previous row's and prefix-summed,
+    which is exact in int32 (wraparound cancels) and gathers nothing.
+    Only rows ``q0..q1`` are scattered, in passes of ``fill + 1`` (a
+    pass's first row is the previous pass's last, there for the
+    difference): on the TPU a scatter costs about as much per entry as
+    a gather, the entries it drops too, so the fill's cost follows the
+    rows the chunk spans, not its size.  The passes run in a ``while``
+    loop unless ``size <= fill``, where one always does.
     """
-    marks = jnp.zeros((size,), jnp.int32)
-    out = []
-    for v in vals:
-        deltas = jnp.diff(v, prepend=jnp.zeros_like(v[:1]))
-        out.append(jnp.cumsum(scatter_sorted(marks, pos, deltas)))
-    return out
+    n_pass = jnp.maximum((q1 - q0 + fill - 1) // fill, 1)
+
+    def fill_pass(p, marks):
+        win = jax.lax.dynamic_slice_in_dim(table, q0 + p * fill, fill + 1,
+                                           axis=1)
+        pos = jnp.maximum(win[0] - base, 0)
+        return tuple(
+            scatter_sorted(m, pos,
+                           jnp.diff(v, prepend=jnp.where(p == 0, 0, v[:1])))
+            for m, v in zip(marks, win))
+
+    marks = (jnp.zeros((size,), jnp.int32),) * table.shape[0]
+    if size <= fill:          # q1 - q0 < size: one pass spans the chunk
+        marks = fill_pass(0, marks)
+    else:
+        _, marks = jax.lax.while_loop(
+            lambda c: c[0] < n_pass, lambda c: (c[0] + 1, fill_pass(*c)),
+            (jnp.int32(0), marks))
+    return [jnp.cumsum(m) for m in marks], n_pass * (fill + 1)
 
 
 def _pick(col: jnp.ndarray, options) -> jnp.ndarray:
@@ -327,12 +356,17 @@ def extend_vertex_chunked(ctx: GraphCtx, app: MiningApp, emb: jnp.ndarray,
     nothing.  Within a chunk every per-parent quantity (the row's slot
     offsets per column, its embedding columns and their CSR ranges, its
     state) reaches the slots by :func:`_forward_fill` instead of a gather
-    per slot; what is left per candidate is the CSR gather of the
+    per slot.  The fill scatters only the rows that start in the chunk,
+    in passes of ``FILL_ROWS``: on the TPU a scatter is charged per
+    entry, the entries it drops too, and a chunk of ``CHUNK_SLOTS``
+    slots spans a few hundred to a few thousand rows on power-law
+    graphs.  What is left per candidate is the CSR gather of the
     candidate itself and the connectivity probes.  Survivors are written
     at their running offset, in slot order, exactly as the one-shot
     compaction writes them.  Everything is kept in 1-D columns: on the
     TPU, flattening an ``[N, k]`` array costs tens of seconds of compile
-    time.  Returns ``(level, new_emb, n_candidates)``.
+    time.  Returns ``(level, new_emb, n_candidates, n_fill)``, the last
+    the entries the fill scattered over the level.
     """
     emb, state = _pad_empty_frontier(emb, state)
     cap, k = emb.shape
@@ -372,8 +406,10 @@ def extend_vertex_chunked(ctx: GraphCtx, app: MiningApp, emb: jnp.ndarray,
         table = [c.astype(jnp.int32)[rid] for c in columns]
         table[0] = jnp.where(live_r, table[0], _INT_MAX)
         table = jnp.stack(table)
-        # a window of chunk + 1 rows covers any chunk: pad past the end
-        pad = jnp.zeros((table.shape[0], chunk + 1), jnp.int32)
+        # a fill pass reads fill + 1 rows from a chunk's rows on: pad
+        # past the end, so that no window's start is clamped
+        fill = min(FILL_ROWS, chunk)
+        pad = jnp.zeros((table.shape[0], fill + 1), jnp.int32)
         table = jnp.concatenate([table, pad.at[0].set(_INT_MAX)], axis=1)
 
     col_idx = ctx.col_idx if ctx.n_edges else jnp.zeros(1, ctx.col_idx.dtype)
@@ -381,13 +417,15 @@ def extend_vertex_chunked(ctx: GraphCtx, app: MiningApp, emb: jnp.ndarray,
     lane = jnp.arange(chunk, dtype=jnp.int32)
 
     def step(carry):
-        c, n_surv, out_vid, out_idx, out_st = carry
+        c, n_surv, n_fill, out_vid, out_idx, out_st = carry
         base = c * chunk
         with jax.named_scope("fill"):
-            q0 = jnp.searchsorted(table[0], base, side="right") - 1
-            win = jax.lax.dynamic_slice_in_dim(table, q0, chunk + 1, axis=1)
-            f = _forward_fill(jnp.maximum(win[0] - base, 0), list(win),
-                              chunk)
+            # the chunk's first and last rows (0 and 0 with no rows)
+            ends = jnp.stack([base, base + chunk - 1])
+            q0, q1 = jnp.maximum(
+                jnp.searchsorted(table[0], ends, side="right") - 1, 0)
+            f, entries = _forward_fill(table, q0, q1, base, chunk, fill)
+            n_fill = n_fill + entries
         with jax.named_scope("draw"):
             slot = base + lane
             live = slot < limit
@@ -435,22 +473,22 @@ def extend_vertex_chunked(ctx: GraphCtx, app: MiningApp, emb: jnp.ndarray,
             if new_st is not None:
                 out_st = scatter_sorted(out_st, at, new_st * keep)
             n_surv = n_surv + jnp.sum(keep)
-        return c + 1, n_surv, out_vid, out_idx, out_st
+        return c + 1, n_surv, n_fill, out_vid, out_idx, out_st
 
     zeros = jnp.zeros((out_cap,), jnp.int32)
-    carry = (jnp.int32(0), jnp.int32(0), zeros, zeros, zeros)
+    carry = (jnp.int32(0), jnp.int32(0), jnp.int32(0), zeros, zeros, zeros)
     if chunk == cand_cap:       # one chunk covers the level; an empty
         carry = step(carry)     # one writes nothing
     else:
         carry = jax.lax.while_loop(lambda c: c[0] * chunk < limit, step,
                                    carry)
-    _, n_new, vid, idx, st = carry
+    _, n_new, n_fill, vid, idx, st = carry
     with jax.named_scope("emit"):
         vid = vid - 1                         # vid is stored plus one
         level = EmbeddingLevel(vid=vid, idx=idx, n=n_new,
                                state=None if upd is None else st)
         new_emb = jnp.concatenate([emb[idx], vid[:, None]], axis=1)
-    return level, new_emb, total
+    return level, new_emb, total, n_fill
 
 
 # ---------------------------------------------------------------------------
@@ -921,8 +959,8 @@ class ReferenceBackend(PhaseBackend):
 
     def inspect_vertex(self, ctx, app, emb, n_valid, state, cand_cap):
         if self._enumerates_in_xla():
-            level, _, total = extend_vertex_chunked(ctx, app, emb, n_valid,
-                                                    state, cand_cap, 1)
+            level, _, total, _ = extend_vertex_chunked(
+                ctx, app, emb, n_valid, state, cand_cap, 1)
             return total, level.n
         _, _, _, add, total = self._vertex_candidates(ctx, app, emb,
                                                       n_valid, state,
@@ -951,7 +989,7 @@ class ReferenceBackend(PhaseBackend):
         level, new_emb = finish_extend_vertex(emb, row, u, add, out_cap,
                                               fuse_filter,
                                               new_state=new_st)
-        return level, new_emb, total
+        return level, new_emb, total, jnp.int32(0)
 
     # -- edge EXTEND (enumeration is the backend-swappable step, like
     #    _vertex_candidates)

@@ -449,7 +449,7 @@ class HostCapPolicy:
         self.caps.append((cand_cap, out_cap))
         return cand_cap, out_cap
 
-    def note_extend(self, n_cand, n_surv, cand_cap: int,
+    def note_extend(self, n_cand, n_surv, n_fill, cand_cap: int,
                     out_cap: int) -> None:
         # out_cap was planned from the inspection pass's exact survivor
         # count; more survivors coming back from extend_pruned means the
@@ -491,16 +491,16 @@ class PlanCapPolicy:
         self._li = 0
         self._fi = 0
         self._ovf = jnp.zeros((), bool)
-        self.counts: list = []        # traced (n_cand, n_surv) per level
+        self.counts: list = []  # traced (n_cand, n_surv, n_fill) per level
 
     def extend_caps(self, pipe):
         cand_cap, out_cap = self.plan.caps[self._li]
         self._li += 1
         return cand_cap, out_cap
 
-    def note_extend(self, n_cand, n_surv, cand_cap: int,
+    def note_extend(self, n_cand, n_surv, n_fill, cand_cap: int,
                     out_cap: int) -> None:
-        self.counts.append((n_cand, n_surv))
+        self.counts.append((n_cand, n_surv, n_fill))
         self._ovf = (self._ovf | (n_cand > cand_cap)
                      | (n_surv > out_cap))
 
@@ -514,10 +514,11 @@ class PlanCapPolicy:
         return self._ovf
 
     def level_counts(self):
-        """int32[levels, 2]: each level's true (candidates, survivors)."""
+        """int32[levels, 3]: each level's true (candidates, survivors)
+        and the entries its forward fill scattered."""
         if not self.counts:
-            return jnp.zeros((0, 2), jnp.int32)
-        return jnp.stack([jnp.stack([c, s]) for c, s in self.counts]
+            return jnp.zeros((0, 3), jnp.int32)
+        return jnp.stack([jnp.stack(c) for c in self.counts]
                          ).astype(jnp.int32)
 
 
@@ -557,8 +558,8 @@ class _ProbePolicy(PlanCapPolicy):
         self.n_keep: list = []
 
     def outputs(self) -> tuple:
-        return (tuple(c for c, _ in self.counts),
-                tuple(s for _, s in self.counts), tuple(self.n_keep))
+        return (tuple(c for c, _, _ in self.counts),
+                tuple(s for _, s, _ in self.counts), tuple(self.n_keep))
 
     def filter_cap(self, n_keep) -> int:
         self.n_keep.append(n_keep)
@@ -722,11 +723,11 @@ def plan_program(plan: MiningPlan, app, backend, fuse_filter: bool = True,
     Vertex plans: ``(ctx, src, dst, n_valid) -> (count, p_map,
     overflowed, level_counts)``; edge plans: ``(ctx, src, dst, eid,
     n_valid) -> (codes, supports, overflowed, level_counts)``, where
-    ``level_counts`` is int32[levels, 2], each level's true (candidates,
-    survivors).  The graph context is the first argument — an input
-    buffer of the executable, never a constant folded into it.  The
-    program is named ``mine_<kind>_<cap0>`` (XLA module
-    ``jit_mine_<kind>_<cap0>``).
+    ``level_counts`` is int32[levels, 3], each level's true (candidates,
+    survivors) and the entries its forward fill scattered.  The graph
+    context is the first argument — an input buffer of the executable,
+    never a constant folded into it.  The program is named
+    ``mine_<kind>_<cap0>`` (XLA module ``jit_mine_<kind>_<cap0>``).
     """
     from repro.core import engine as E
 
@@ -823,7 +824,7 @@ class MiningExecutor:
         self.n_executions = 0
         self.n_replans = 0
         self.wait_s = 0.0             # host seconds blocked on the device
-        self.last_level_counts = None  # device int32[levels, 2]
+        self.last_level_counts = None  # device int32[levels, 3]
 
     # -- plan management ----------------------------------------------------
 
@@ -933,22 +934,24 @@ class MiningExecutor:
         record them under the host path's names; returns span args."""
         counts = np.asarray(counts).tolist()
         args = {}
-        for li, ((cand_cap, out_cap), (nc, ns)) in enumerate(
+        for li, ((cand_cap, out_cap), (nc, ns, nf)) in enumerate(
                 zip(self._plan.caps, counts)):
             level = li + 2
             args[f"candidates.level{level}"] = nc
             args[f"survivors.level{level}"] = ns
+            args[f"fill_entries.level{level}"] = nf
             if overflowed:
                 continue        # the retry's counts are the run's
             _M.inc("mine.candidates", nc, level=level)
             _M.inc("mine.survivors", ns, level=level)
+            _M.inc("mine.fill_entries", nf, level=level)
             _M.set_gauge("mine.cap_utilization",
                          ns / out_cap if out_cap else 0.0, level=level)
             _M.set_gauge("mine.cand_cap_utilization",
                          nc / cand_cap if cand_cap else 0.0, level=level)
         if not overflowed:
             _M.observe("executor.replay_candidates",
-                       sum(nc for nc, _ in counts))
+                       sum(nc for nc, _, _ in counts))
         return args
 
     def _run_with_retry(self, *args):

@@ -133,8 +133,8 @@ def test_extend_pruned_equals_composed_trio(seed, n, p, app_idx, backend):
     m, emb, nv, state = _level1_inputs(g, app, backend)
     be = m.backend
     cand_cap, out_cap = 2048, 512
-    level, new_emb, n_cand = be.extend_pruned(m.ctx, app, emb, nv, state,
-                                              cand_cap, out_cap)
+    level, new_emb, n_cand, _ = be.extend_pruned(m.ctx, app, emb, nv,
+                                                 state, cand_cap, out_cap)
     ref_level, ref_emb, ref_cand = _composed_trio(m.ctx, app, emb, nv,
                                                   state, cand_cap, out_cap)
     assert int(n_cand) == int(ref_cand)
@@ -153,8 +153,8 @@ def test_every_registered_backend_serves_extend_pruned(er_graph):
         be = get_backend(name)
         app = make_tc_app()
         m, emb, nv, state = _level1_inputs(er_graph, app, name)
-        level, _, n_cand = be.extend_pruned(m.ctx, app, emb, nv, state,
-                                            1024, 256)
+        level, _, n_cand, _ = be.extend_pruned(m.ctx, app, emb, nv, state,
+                                               1024, 256)
         assert int(n_cand) > 0 and int(level.n) > 0
 
 

@@ -4,9 +4,9 @@
   scopes in its compiled HLO text (``Compiled.as_text()``), and
   ``Miner.op_scopes()`` maps its instructions to them.
 * Replay-path counters: with tracing on, a replay reads its per-level
-  (candidates, survivors) back and records them under the host path's
-  names, with the host path's values; with tracing off nothing is read
-  back and the registry gains no level entry.
+  (candidates, survivors, fill entries) back and records them under the
+  host path's names, with the host path's values; with tracing off
+  nothing is read back and the registry gains no level entry.
 """
 import numpy as np
 import pytest
@@ -19,8 +19,8 @@ from repro.obs import metrics, trace
 
 _RUNS: dict = {}
 
-LEVEL_METRICS = ("mine.candidates", "mine.survivors", "mine.cap_utilization",
-                 "mine.cand_cap_utilization")
+LEVEL_METRICS = ("mine.candidates", "mine.survivors", "mine.fill_entries",
+                 "mine.cap_utilization", "mine.cand_cap_utilization")
 
 
 def _levels(name: str) -> dict:
@@ -39,7 +39,7 @@ def _mined(app: str) -> dict:
         metrics.reset()
         host = miner.run()
         out = {"miner": miner, "host_count": host.count,
-               "host": {n: _levels(n) for n in LEVEL_METRICS[:2]}}
+               "host": {n: _levels(n) for n in LEVEL_METRICS[:3]}}
         metrics.reset()
         trace.enable()
         replay = miner.run()
@@ -85,21 +85,60 @@ def test_executor_carries_level_phase_scopes(app):
 def test_replay_counts_equal_host_counts(app):
     run = _mined(app)
     assert run["replay_count"] == run["host_count"]
-    for name in LEVEL_METRICS[:2]:
+    for name in LEVEL_METRICS[:3]:
         assert run["replay"][name] == run["host"][name], name
     (ex,) = run["miner"]._executors.values()
     counts = np.asarray(ex.last_level_counts)
-    assert counts.shape == (len(ex.plan.caps), 2)
-    for li, (nc, ns) in enumerate(counts.tolist()):
+    assert counts.shape == (len(ex.plan.caps), 3)
+    for li, (nc, ns, nf) in enumerate(counts.tolist()):
         level = li + 2
-        assert (nc, ns) == (run["host"]["mine.candidates"][level],
-                            run["host"]["mine.survivors"][level])
+        assert (nc, ns, nf) == tuple(run["host"][name][level]
+                                     for name in LEVEL_METRICS[:3])
         out_cap = ex.plan.caps[li][1]
         assert run["replay"]["mine.cap_utilization"][level] == ns / out_cap
     (span,) = run["span"]
     last = len(counts) + 1
     assert span["args"][f"candidates.level{last}"] == counts[-1][0]
     assert span["args"][f"survivors.level{last}"] == counts[-1][1]
+    assert span["args"][f"fill_entries.level{last}"] == counts[-1][2]
+
+
+def test_traced_replay_counts_fill_entries(monkeypatch):
+    """With chunks of 16 slots and fill passes of 2 rows, a traced replay
+    records as each level's ``mine.fill_entries`` the passes its chunks
+    took, 3 entries a pass: a chunk's rows are those starting in it and
+    the one running into it, and a pass reaches 2 rows past its first."""
+    from repro.core.embedding_list import materialize
+    from repro.core.phases import reference as R
+
+    monkeypatch.setattr(R, "CHUNK_SLOTS", 16)
+    monkeypatch.setattr(R, "FILL_ROWS", 2)
+    app = make_app("4-cf", 2)
+    miner = Miner(G.erdos_renyi(40, 0.2, seed=3), app)
+    trace.disable()
+    levels = miner.run().levels           # plans the capacities
+    metrics.reset()
+    trace.enable()
+    miner.run()
+    trace.disable()
+    fill = _levels("mine.fill_entries")
+    metrics.reset()
+    (ex,) = miner._executors.values()
+    assert sorted(fill) == [2, 3]
+    for level, (cand_cap, _) in enumerate(ex.plan.caps, start=2):
+        front = levels[:level - 1]
+        emb = materialize(front)
+        deg = R.vertex_ext_degrees(miner.ctx, app, emb, front[-1].n)
+        sizes = np.asarray(deg).sum(axis=1)
+        starts = np.cumsum(sizes)[sizes > 0] - sizes[sizes > 0]
+        chunk = min(cand_cap, 16)
+        want = 0
+        for base in range(0, min(int(sizes.sum()), cand_cap), chunk):
+            q0, q1 = (np.searchsorted(starts, at, side="right") - 1
+                      for at in (base, base + chunk - 1))
+            want += max(-(-(q1 - q0) // 2), 1) * 3
+        assert want > 3 * len(range(0, int(sizes.sum()), chunk))
+        assert fill[level] == want, level
 
 
 def test_untraced_replay_reads_nothing_back():

@@ -182,7 +182,7 @@ def test_extend_pruned_bitwise_parity(aname, make_app, seed, kbackend):
         state = (app.init_state(m.ctx, emb, jnp.int32(n))
                  if app.init_state is not None
                  else jnp.zeros(emb.shape[:1], jnp.int32))
-        level, new_emb, n_cand = m.backend.extend_pruned(
+        level, new_emb, n_cand, _ = m.backend.extend_pruned(
             m.ctx, app, emb, jnp.int32(n), state, 1024, 512)
         st = (None if level.state is None else np.asarray(level.state))
         results.append((np.asarray(level.vid), np.asarray(level.idx),
@@ -224,21 +224,25 @@ CHUNKED_APPS = PRUNED_APPS + [
 @pytest.mark.parametrize("pack_bytes", [0, 4 << 20], ids=["csr", "bitmap"])
 @pytest.mark.parametrize("caps", [(1024, 512), (40, 512), (1024, 7)],
                          ids=["roomy", "cand-overflow", "out-overflow"])
-@pytest.mark.parametrize("chunk", [16, 1 << 16],
-                         ids=["many-chunks", "one-chunk"])
+@pytest.mark.parametrize("chunk,fill_rows", [(16, None), (16, 2),
+                                             (1 << 16, None)],
+                         ids=["many-chunks", "small-fill", "one-chunk"])
 def test_chunked_extend_matches_one_shot(aname, make_app, pack_bytes, caps,
-                                         chunk, monkeypatch):
+                                         chunk, fill_rows, monkeypatch):
     """The reference backend's chunked extend (per-parent values forward-
     filled over each chunk of slots) returns the bit-identical level,
     embeddings and counts of the one-shot enumerate-filter-compact, on
     every level of the run, with chunks much smaller than the level or
-    one chunk for all of it, and under candidate or survivor overflow."""
+    one chunk for all of it, with fill passes of two rows (several a
+    chunk), and under candidate or survivor overflow."""
     import jax.numpy as jnp
     from repro.core.api import resolve_state_kernel
     from repro.core.embedding_list import init_level0_vertex, materialize
     from repro.core.phases import reference as R
 
     monkeypatch.setattr(R, "CHUNK_SLOTS", chunk)
+    if fill_rows is not None:
+        monkeypatch.setattr(R, "FILL_ROWS", fill_rows)
     cand_cap, out_cap = caps
     g = G.erdos_renyi(24, 0.3, seed=3, labels=2)
     app = make_app()
@@ -258,7 +262,7 @@ def test_chunked_extend_matches_one_shot(aname, make_app, pack_bytes, caps,
                   R.apply_state_kernel(m.ctx, upd, emb, row, u, slot, state))
         want, want_emb = R.finish_extend_vertex(emb, row, u, add, out_cap,
                                                 new_state=new_st)
-        got, got_emb, got_total = R.extend_vertex_chunked(
+        got, got_emb, got_total, _ = R.extend_vertex_chunked(
             m.ctx, app, emb, n, state, cand_cap, out_cap)
         assert int(got_total) == int(total)
         assert int(got.n) == int(want.n)
@@ -271,6 +275,65 @@ def test_chunked_extend_matches_one_shot(aname, make_app, pack_bytes, caps,
         if int(total) > cand_cap or int(want.n) > out_cap:
             break
         emb, state, n = want_emb, want.state, want.n
+
+
+_I32 = np.iinfo(np.int32)
+FILL_CASES = {                   # row sizes (slots per row) of a level
+    "rows-of-one": [1] * 40,
+    "row-over-chunks": [3, 100, 2],
+    "rows-on-pass-edges": [1] * 8 + [8] + [1] * 4 + [12] + [1] * 9,
+    "ragged": [5, 1, 1, 2, 9, 1, 3, 1, 1, 1, 17, 2, 1, 4, 1, 1],
+}
+
+
+def _window_fill(table: np.ndarray, base: int, size: int) -> np.ndarray:
+    """The forward fill over a window of ``size + 1`` rows from the
+    chunk's first row (fewer at the table's end): every row's difference
+    to the one before, scattered at its start (past the chunk dropped),
+    prefix-summed."""
+    q0 = np.searchsorted(table[0], base, side="right") - 1
+    win = table[:, q0:q0 + size + 1]
+    pos = np.maximum(win[0].astype(np.int64) - base, 0)
+    deltas = np.diff(win, axis=1, prepend=np.zeros((len(win), 1), np.int32))
+    marks = np.zeros((len(win), size), np.int32)
+    keep = pos < size
+    for r in range(len(win)):
+        np.add.at(marks[r], pos[keep], deltas[r][keep])
+    return np.cumsum(marks, axis=1, dtype=np.int32)
+
+
+@pytest.mark.parametrize("case", sorted(FILL_CASES))
+@pytest.mark.parametrize("fill", [4, 16], ids=["passes", "one-pass"])
+def test_forward_fill_passes_match_one_window(case, fill):
+    """The fill in passes of ``fill`` rows (several a chunk of 16 slots,
+    or one) equals the fill over a whole window of a chunk's size, bit
+    for bit: int32 values that wrap, a row longer than a chunk, a chunk
+    whose rows end on a pass's edge, and a last chunk past the table's
+    end (no dead rows, only the pad).  It scattered ``fill + 1`` entries
+    a pass, as many passes as the chunk's rows need."""
+    import jax
+    from repro.core.phases import reference as R
+
+    size = 16
+    sizes = np.asarray(FILL_CASES[case])
+    starts = np.concatenate([[0], np.cumsum(sizes)[:-1]]).astype(np.int32)
+    rng = np.random.default_rng(len(sizes))
+    vals = rng.integers(_I32.min, _I32.max, (3, len(sizes)), np.int32,
+                        endpoint=True)
+    vals[0, ::2] = _I32.max          # differences that wrap around
+    vals[1, 1::2] = _I32.min
+    pad = np.zeros((4, fill + 1), np.int32)     # as the extend pads
+    pad[0] = _I32.max
+    table = np.concatenate([np.vstack([starts, vals]), pad], axis=1)
+    run = jax.jit(R._forward_fill, static_argnums=(4, 5))
+    for base in range(0, int(sizes.sum()), size):
+        q0, q1 = (np.searchsorted(starts, at, side="right") - 1
+                  for at in (base, base + size - 1))
+        got, entries = run(jnp.asarray(table), jnp.int32(q0), jnp.int32(q1),
+                           jnp.int32(base), size, fill)
+        np.testing.assert_array_equal(np.stack(got),
+                                      _window_fill(table, base, size))
+        assert int(entries) == max(-(-(q1 - q0) // fill), 1) * (fill + 1)
 
 
 def test_pruned_kernel_matches_oracle():
